@@ -1,17 +1,20 @@
-"""Roofline table from the dry-run results (deliverable g): per-cell
-terms, dominant bottleneck, useful-FLOPs ratio."""
+"""Roofline table from dry-run results (deliverable g): per-cell terms,
+dominant bottleneck, useful-FLOPs ratio. The repo commits no dry-run
+results, so without ``path`` (a JSON written by
+``python -m repro.launch.dryrun --out <path>``) the table is reported
+as not measured."""
 from __future__ import annotations
 
 import json
 from pathlib import Path
 
-DRYRUN = Path("results/dryrun.json")
 
-
-def rows(mesh: str = "single"):
-    if not DRYRUN.exists():
-        return [("roofline[missing]", 0.0, "run repro.launch.dryrun first")]
-    res = json.loads(DRYRUN.read_text())
+def rows(path: str = None, mesh: str = "single"):
+    if path is None:
+        return [("roofline[not_measured]", 0.0,
+                 "no dry-run results given; run repro.launch.dryrun --out "
+                 "<path> and pass the path")]
+    res = json.loads(Path(path).read_text())
     out = []
     for key, v in sorted(res.items()):
         if v.get("status") != "ok" or not key.endswith(f"|{mesh}"):

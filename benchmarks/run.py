@@ -36,18 +36,24 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
-    from benchmarks import crypto_micro, figures, perf_sim, roofline_table
-    from benchmarks import serving_specialization
+    import importlib
+
+    def section(module, fn, *args, **kw):
+        # import on first use: only the crypto section loads JAX, and it
+        # runs last, after perf has forked its worker pool from a parent
+        # that holds no device
+        return lambda: getattr(importlib.import_module(
+            f"benchmarks.{module}"), fn)(*args, **kw)
 
     sections = [
-        ("fig5", lambda: figures.bench_fig5_fig6()),
-        ("fig2", lambda: figures.bench_fig2()),
-        ("fig7", lambda: figures.bench_fig7()),
-        ("cohort", lambda: figures.bench_cohort()),
-        ("crypto", crypto_micro.rows),
-        ("serving", serving_specialization.rows),
-        ("roofline", roofline_table.rows),
-        ("perf", lambda: perf_sim.rows(smoke=True)),
+        ("fig5", section("figures", "bench_fig5_fig6")),
+        ("fig2", section("figures", "bench_fig2")),
+        ("fig7", section("figures", "bench_fig7")),
+        ("cohort", section("figures", "bench_cohort")),
+        ("serving", section("serving_specialization", "rows")),
+        ("roofline", section("roofline_table", "rows")),
+        ("perf", section("perf_sim", "rows", smoke=True)),
+        ("crypto", section("crypto_micro", "rows")),
     ]
     print("name,us_per_call,derived")
     failed = 0

@@ -6,7 +6,8 @@ interleaved with decode (every prefill stalls all co-located decodes —
 the 2 ms-tail analogue). Specialized: ``SpecializedPolicy`` over a
 prefill/decode ``Topology`` with asymmetric stealing and KV handoffs.
 Metric: inter-token latency (ITL) tail and its variability. Service
-times derive from the dry-run roofline of a real cell.
+times come from the committed ``POOL_MODEL`` constants, a roofline
+estimate for codeqwen1.5-7b (not a device measurement).
 
   PYTHONPATH=src python benchmarks/serving_specialization.py [--smoke]
 """
@@ -14,20 +15,18 @@ from __future__ import annotations
 
 import argparse
 import copy
-import json
 import time
-from pathlib import Path
 
 from repro.sched import SharedBaselinePolicy, SpecializedPolicy, Topology
 from repro.sched.cluster import (ClusterConfig, ClusterEngine,
                                  ClusterTopology)
-from repro.sched.engine import (Engine, PoolModel, ServeConfig,
-                                pool_model_from_dryrun)
+from repro.sched.engine import Engine, PoolModel, ServeConfig
 from repro.sched.policy import make_cluster_policy
 from repro.sched.replay import headline_metrics
 from repro.sched.workload import poisson_workload, scenario_trace
 
-DRYRUN = Path("results/dryrun.json")
+POOL_MODEL = PoolModel(prefill_ms_per_ktok=326.0, decode_fixed_ms=757.0,
+                       decode_ms_per_seq=23.6)
 
 
 def run(arch: str = "codeqwen1.5-7b", n_devices: int = 16,
@@ -35,11 +34,7 @@ def run(arch: str = "codeqwen1.5-7b", n_devices: int = 16,
         util: float = 0.5, seed: int = 3, scenario: str = None,
         cluster_shards: int = 2,
         cluster_policy: str = "cluster-adaptive"):
-    if DRYRUN.exists():
-        pm = pool_model_from_dryrun(json.loads(DRYRUN.read_text()), arch)
-    else:
-        pm = PoolModel(prefill_ms_per_ktok=326.0, decode_fixed_ms=757.0,
-                       decode_ms_per_seq=23.6)
+    pm = POOL_MODEL
     if scenario is not None:
         # one scenario trace from the workload subsystem, replayed
         # identically under both setups

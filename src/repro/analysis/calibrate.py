@@ -160,10 +160,10 @@ def kernel_timelines(machine: MachineModel = MachineModel()
         segment(lambda k, n: chacha20_keystream(
             k, n, 1, n_blocks=256, tile=256, interpret=True),
             key, nonce, name="chacha20", machine=machine),
-        segment(lambda a, b, c: flash_attention(a, b, c), q, q, q,
-                name="flash_attention", machine=machine),
-        segment(lambda a, b, c, l: flash_decode(a, b, c, l), qd, kv, kv,
-                lens, name="flash_decode", machine=machine),
+        segment(lambda a, b, c: flash_attention(a, b, c, interpret=True),
+                q, q, q, name="flash_attention", machine=machine),
+        segment(lambda a, b, c, l: flash_decode(a, b, c, l, interpret=True),
+                qd, kv, kv, lens, name="flash_decode", machine=machine),
     ]
 
 
@@ -294,8 +294,8 @@ def _kernel_differentials(tol: float) -> Dict[str, Optional[Dict]]:
                                         interpret=True),
         key, nonce, name="chacha20", tol=tol)
     out["chacha20"] = d.to_dict() if d else None
-    d = differential(lambda a, b, c: flash_attention(a, b, c), q, q, q,
-                     name="flash_attention", tol=tol)
+    d = differential(lambda a, b, c: flash_attention(a, b, c, interpret=True),
+                     q, q, q, name="flash_attention", tol=tol)
     out["flash_attention"] = d.to_dict() if d else None
     return out
 

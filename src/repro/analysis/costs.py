@@ -20,7 +20,7 @@ flops, and dtype-aware bytes moved. Control flow is costed explicitly:
                      MXU flops as ZERO;
   * ``pallas_call``  kernel body x prod(grid) — TPU grids execute the
                      kernel once per grid cell;
-  * ``pjit`` / ``remat`` / ``custom_*`` / ``shard_map``  transparent
+  * ``jit`` / ``remat2`` / ``custom_*`` / ``shard_map``  transparent
                      descent into the inner jaxpr.
 
 Everything else is elementwise: one flop per output element, bytes =
@@ -36,9 +36,8 @@ import numpy as np
 MXU_PRIMS = {"dot_general", "conv_general_dilated"}
 
 # transparent call-like primitives: descend, multiplier 1
-_CALL_PRIMS = {"pjit", "closed_call", "custom_vjp_call", "custom_jvp_call",
-               "custom_vjp_call_jaxpr", "custom_jvp_call_jaxpr", "remat",
-               "checkpoint", "remat2", "shard_map", "core_call", "xla_call"}
+_CALL_PRIMS = {"jit", "closed_call", "custom_vjp_call", "custom_jvp_call",
+               "remat2", "shard_map"}
 
 
 @dataclass(frozen=True)

@@ -2,7 +2,7 @@
 sub-function granularity.
 
 ``segment`` walks a jaxpr's equation sequence in program order
-(descending into scan/while/pjit/pallas bodies) and emits an ordered
+(descending into scan/while/jit/pallas bodies) and emits an ordered
 timeline of :class:`Region` s. Each leaf equation is classified into a
 license level — the TPU analogue of the x86 power licenses:
 

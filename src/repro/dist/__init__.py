@@ -9,37 +9,9 @@ Submodules:
 * ``collectives`` — ``compressed_allreduce`` (int8 + error feedback) and
                     ``hierarchical_allreduce`` (pod-aware rs/ar/ag).
 * ``pipeline``    — ``gpipe_apply`` microbatched pipeline parallelism.
-
-Importing the package also installs a small forward-compat shim: newer
-JAX exposes ``jax.shard_map(..., check_vma=...)`` while older releases
-only have ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
-All repro code (and the seed tests) is written against the new spelling,
-so on old JAX we bridge the gap here, before any submodule runs.
 """
-from __future__ import annotations
-
-import jax
-
-
-def _install_shard_map_compat():
-    if hasattr(jax, "shard_map"):
-        return
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-                  check_rep=None, **kwargs):
-        if check_rep is None:
-            check_rep = True if check_vma is None else bool(check_vma)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_rep, **kwargs)
-
-    jax.shard_map = shard_map
-
-
-_install_shard_map_compat()
-
-from repro.dist.context import DistContext, make_dist, no_dist  # noqa: E402
-from repro.dist.sharding import sanitize_specs, tree_shardings  # noqa: E402
+from repro.dist.context import DistContext, make_dist, no_dist
+from repro.dist.sharding import sanitize_specs, tree_shardings
 
 __all__ = ["DistContext", "make_dist", "no_dist", "sanitize_specs",
            "tree_shardings"]

@@ -70,7 +70,7 @@ def _chacha20_kernel(key_ref, nonce_ref, ctr_ref, out_ref):
 
 def keystream(key: jnp.ndarray, nonce: jnp.ndarray, counter0: int,
               *, n_blocks: int, tile: int = TILE,
-              interpret: bool = True) -> jnp.ndarray:
+              interpret: bool = False) -> jnp.ndarray:
     """ChaCha20 keystream: [n_blocks, 16] u32 (64 bytes per row).
 
     key: [8] u32 (little-endian words), nonce: [3] u32, counter0: scalar
@@ -83,7 +83,7 @@ def keystream(key: jnp.ndarray, nonce: jnp.ndarray, counter0: int,
 @functools.partial(jax.jit, static_argnames=("n_blocks", "tile", "interpret"))
 def _keystream(key: jnp.ndarray, nonce: jnp.ndarray, ctr: jnp.ndarray,
                *, n_blocks: int, tile: int = TILE,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: bool = False) -> jnp.ndarray:
     assert n_blocks % tile == 0, (n_blocks, tile)
     grid = (n_blocks // tile,)
     return pl.pallas_call(

@@ -58,7 +58,7 @@ def _fd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def flash_decode(q, k, v, lengths, *, block_k: int = 512,
-                 interpret: bool = True):
+                 interpret: bool = False):
     """q [B,H,D], k/v [B,KVH,S,D], lengths [B] -> [B,H,D]."""
     B, H, D = q.shape
     KVH, S = k.shape[1], k.shape[2]

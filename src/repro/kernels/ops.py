@@ -10,7 +10,7 @@ from repro.kernels.flash_attention import flash_attention
 
 def chacha20_encrypt(data_u32: jnp.ndarray, key: jnp.ndarray,
                      nonce: jnp.ndarray, counter0: int = 1,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool = False) -> jnp.ndarray:
     """XOR data (flattened to u32 words, multiple of 16 per block) with the
     keystream. data_u32: [n_blocks, 16] u32."""
     n_blocks = data_u32.shape[0]

@@ -1,25 +1,25 @@
-"""Serving driver: a real (small) model behind the specialization engine.
+"""Serving driver: a real model behind the specialization engine.
 
-Runs actual jitted prefill/decode of a reduced-config model on CPU,
-driven by the event-driven engine (`repro.sched.engine`) — the same
-scheduler code the benchmarks exercise, with service times *measured*
-from the real jitted calls instead of modelled. The annotation workflow
-runs end-to-end: the region analyzer (`repro.analysis`) segments the
-two step functions into phase timelines, the calibrated tag set from
-``analysis/derived.json`` (falling back to a fresh ``tag_heavy`` for
-uncalibrated archs) marks the heavy (AVX-analogue) phase, and the
-``SpecializedPolicy`` confines it to the prefill pool of a two-pool
-``Topology``. The engine's frequency domain likewise uses the
-calibrated per-arch license levels when available.
+Runs jitted prefill/decode of a model at its published width (or the
+CPU-sized config with ``--reduced``), driven by the event-driven engine
+(`repro.sched.engine`) — the same scheduler code the benchmarks
+exercise, with service times *measured* from the jitted calls instead of
+modelled. The annotation workflow runs end-to-end: the region analyzer
+(`repro.analysis`) segments the two step functions into phase
+timelines, the calibrated tag set from ``analysis/derived.json``
+(falling back to a fresh ``tag_heavy`` for uncalibrated archs) marks the
+heavy (AVX-analogue) phase, and the ``SpecializedPolicy`` confines it to
+the prefill pool of a two-pool ``Topology``. The engine's frequency
+domain likewise uses the calibrated per-arch license levels when
+available.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
-      --requests 16 --prompt 64 --max-new 16
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \\
+      --requests 16 --prompt 64 --max-new 16 --reduced
 
-``--mode loop`` keeps the plain batched loop (no scheduler) for
-comparison; ``--mode cluster`` shards the engine across the dist
-layer — N shard engines behind the frequency-aware router
-(`repro.sched.cluster`), each shard's jitted prefill/decode executor
-running on its own ``DistContext`` mesh slice of the local devices.
+``--mode cluster`` runs N one-device engine shards behind the
+frequency-aware router (`repro.sched.cluster`): shard ``i`` holds its
+own copy of the parameters, its caches and its prompts on
+``jax.devices()[i]``.
 """
 import argparse
 import dataclasses
@@ -28,10 +28,12 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.analysis import derived, segment, tag_heavy
 from repro.configs import get_arch
-from repro.dist.context import DistContext, make_dist, no_dist
+from repro.dist.context import no_dist
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.sched import (ClusterConfig, ClusterEngine, ClusterTopology,
                          SpecializedPolicy, Topology)
@@ -39,29 +41,89 @@ from repro.sched.engine import Engine, Request, ServeConfig
 from repro.sched.workload import load_trace
 
 
+def _greedy(logits):
+    """Next token [B,1] and whether every logit of the step is finite."""
+    return (jnp.argmax(logits, -1).astype(jnp.int32)[:, None],
+            jnp.isfinite(logits).all())
+
+
+def serve_steps(model, max_seq: int):
+    """The two step functions the executor compiles, each returning
+    ``(next_token, all_logits_finite, cache, lengths)``:
+    ``prefill(params, tokens)`` fills a fresh ``max_seq`` cache, and
+    ``decode(params, cache, token, lengths)`` appends one token."""
+    def prefill(p, toks):
+        cache = model.init_cache(p, {"tokens": toks}, toks.shape[0],
+                                 max_seq)
+        logits, cache = model.prefill(p, {"tokens": toks}, cache)
+        return (*_greedy(logits), cache,
+                jnp.full((toks.shape[0],), toks.shape[1], jnp.int32))
+
+    def decode(p, cache, tok, lengths):
+        logits, cache = model.decode_step(p, cache, tok, lengths)
+        return (*_greedy(logits), cache, lengths + 1)
+
+    return prefill, decode
+
+
+def placed(s, sharding):
+    """``s``'s shape and dtype, placed on ``sharding``."""
+    return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding)
+
+
 class RealModelExecutor:
-    """Engine executor that runs real jitted prefill/decode steps.
+    """Engine executor that runs real jitted prefill/decode steps on one
+    device.
 
     The engine calls ``prefill``/``decode`` when its schedule says so;
     we execute the actual computation and return the measured wall-clock
-    duration in ms, which becomes the simulated service time. Per-request
-    KV caches live here, keyed by request id — the handoff the engine
-    charges between pools corresponds to moving one of these caches.
+    duration in ms, which becomes the simulated service time. The
+    parameters, the per-request KV caches (keyed by request id) and the
+    prompts all live on ``device`` — the handoff the engine charges
+    between pools corresponds to moving one of these caches. Request
+    ``rid``'s prompt is drawn from ``(seed, rid)``, so it does not depend
+    on which executor serves it or in what order. Every emitted token is
+    recorded in ``tokens[rid]``.
     """
 
     def __init__(self, model, params, vocab: int, prompt_len: int,
-                 max_seq: int, seed: int = 0):
-        self.model = model
-        self.params = params
+                 max_seq: int, device, seed: int = 0):
         self.vocab = vocab
         self.prompt_len = prompt_len
-        self.max_seq = max_seq
-        self.rng = np.random.default_rng(seed)
-        self.state = {}          # rid -> (cache, last_tok, length)
-        self.prefill_j = jax.jit(
-            lambda p, t, c: model.prefill(p, {"tokens": t}, c))
-        self.decode_j = jax.jit(
-            lambda p, c, t, l: model.decode_step(p, c, t, l))
+        self.seed = seed
+        self.device = device
+        self.params = jax.device_put(params, device)
+        self.state = {}          # rid -> (cache, last_tok, lengths)
+        self.tokens = {}         # rid -> emitted tokens, device [1,1] each
+        self._finite = []        # per step: every logit finite (device)
+        self._steps = serve_steps(model, max_seq)
+        self.prefill_j = self.decode_j = None
+
+    def compile(self) -> float:
+        """Compile prefill and decode for this device, and run each
+        once, ahead of the run: no compile or first-call cost lands in
+        a measured service time. Returns the seconds it took."""
+        t0 = time.perf_counter()
+        prefill, decode = self._steps
+        on = SingleDeviceSharding(self.device)
+        toks = jax.ShapeDtypeStruct((1, self.prompt_len), jnp.int32,
+                                    sharding=on)
+        self.prefill_j = jax.jit(prefill).lower(self.params, toks).compile()
+        tok, _, cache, lengths = jax.tree.map(
+            lambda s: placed(s, on), jax.eval_shape(prefill, self.params,
+                                                    toks))
+        self.decode_j = jax.jit(decode).lower(self.params, cache, tok,
+                                              lengths).compile()
+        tok, _, cache, lengths = self.prefill_j(
+            self.params, jax.device_put(np.zeros(toks.shape, np.int32),
+                                        self.device))
+        self.decode_j(self.params, cache, tok, lengths)[0].block_until_ready()
+        return time.perf_counter() - t0
+
+    def prompt(self, rid: int) -> np.ndarray:
+        rng = np.random.default_rng((self.seed, rid))
+        return rng.integers(0, self.vocab, size=(1, self.prompt_len),
+                            dtype=np.int32)
 
     def prefill(self, req: Request, chunk: int, pool: str,
                 ndev: int) -> float:
@@ -70,55 +132,75 @@ class RealModelExecutor:
         # same request are free — total charged time stays the real cost
         if req.rid in self.state:
             return 0.0
-        toks = jnp.asarray(self.rng.integers(
-            0, self.vocab, size=(1, self.prompt_len)), dtype=jnp.int32)
-        cache = self.model.init_cache(self.params, {"tokens": toks}, 1,
-                                      self.max_seq)
-        t0 = time.time()
-        logits, cache = self.prefill_j(self.params, toks, cache)
-        logits.block_until_ready()
-        dur_ms = (time.time() - t0) * 1e3
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-        self.state[req.rid] = (cache, tok,
-                               jnp.full((1,), self.prompt_len, jnp.int32))
+        toks = jax.device_put(self.prompt(req.rid), self.device)
+        t0 = time.perf_counter()
+        tok, ok, cache, lengths = self.prefill_j(self.params, toks)
+        tok.block_until_ready()
+        dur_ms = (time.perf_counter() - t0) * 1e3
+        self.state[req.rid] = (cache, tok, lengths)
+        self.tokens[req.rid] = [tok]
+        self._finite.append(ok)
         return dur_ms
 
     def decode(self, batch, pool: str, ndev: int) -> float:
-        t0 = time.time()
+        t0 = time.perf_counter()
         for req in batch:
-            cache, tok, length = self.state[req.rid]
-            logits, cache = self.decode_j(self.params, cache, tok, length)
-            logits.block_until_ready()
-            if req.generated + 1 >= req.max_new:
-                # request finishes with this token: drop its KV cache so
-                # executor memory scales with concurrency, not total served
-                self.state.pop(req.rid)
-            else:
-                tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-                self.state[req.rid] = (cache, tok, length + 1)
-        return (time.time() - t0) * 1e3
+            cache, tok, lengths = self.state.pop(req.rid)
+            tok, ok, cache, lengths = self.decode_j(self.params, cache, tok,
+                                                    lengths)
+            tok.block_until_ready()
+            self.tokens[req.rid].append(tok)
+            self._finite.append(ok)
+            # a request that finishes with this token drops its KV cache,
+            # so executor memory scales with concurrency, not total served
+            if req.generated + 1 < req.max_new:
+                self.state[req.rid] = (cache, tok, lengths)
+        return (time.perf_counter() - t0) * 1e3
+
+    def emitted(self) -> dict:
+        """rid -> the greedy tokens this executor emitted, as ints."""
+        return {rid: [int(t[0, 0]) for t in jax.device_get(toks)]
+                for rid, toks in self.tokens.items()}
+
+    def all_finite(self) -> bool:
+        """Whether every logit of every step run so far was finite."""
+        return bool(np.all(jax.device_get(self._finite)))
+
+
+@dataclasses.dataclass
+class ServeRun:
+    """What ``main`` returns: the run's metrics, the executors by name
+    (one per engine shard) and the seconds spent compiling before the
+    measured window."""
+    metrics: object
+    executors: dict
+    compile_s: float
 
 
 def identify_heavy_phase(model, params, batch: int, prompt: int,
                          max_seq: int, arch: str = None):
     """§3.3 identification workflow on the two step functions.
 
-    Segments both entrypoints into region timelines and returns
-    ``(timelines, tags)``. Tags come from the committed calibration
-    artifact (``analysis/derived.json``) when this arch was calibrated —
-    the same derivation the intermittency lint gates on, so serve can
-    never silently run an entrypoint the analyzer considers heavy
-    untagged — and from a fresh ``tag_heavy`` over the just-built
-    timelines otherwise."""
-    toks = jnp.zeros((batch, prompt), jnp.int32)
-    cache = model.init_cache(params, {"tokens": toks}, batch, max_seq)
+    Segments both entrypoints into region timelines (from shapes only:
+    nothing is allocated) and returns ``(timelines, tags, source)``.
+    Tags come from the committed calibration artifact
+    (``analysis/derived.json``) when this arch was calibrated — the same
+    derivation the intermittency lint gates on, so serve can never
+    silently run an entrypoint the analyzer considers heavy untagged —
+    and from a fresh ``tag_heavy`` over the just-built timelines
+    otherwise."""
+    toks = jax.ShapeDtypeStruct((batch, prompt), jnp.int32)
+    cache = jax.eval_shape(
+        lambda p, t: model.init_cache(p, {"tokens": t}, batch, max_seq),
+        params, toks)
 
     timelines = [
         segment(lambda p, t, c: model.prefill(p, {"tokens": t}, c),
                 params, toks, cache, name="prefill"),
         segment(lambda p, c, t, l: model.decode_step(p, c, t, l),
-                params, cache, toks[:, :1],
-                jnp.full((batch,), prompt, jnp.int32), name="decode_step"),
+                params, cache, jax.ShapeDtypeStruct((batch, 1), jnp.int32),
+                jax.ShapeDtypeStruct((batch,), jnp.int32),
+                name="decode_step"),
     ]
     committed = derived.workloads().get(arch) if arch else None
     if committed:
@@ -151,53 +233,71 @@ def _print_identification(timelines, tags, src) -> str:
     return heavy
 
 
-def run_engine(args, cfg, model, params):
-    """Real-model serving through the Policy/Topology engine."""
+def _requests(args) -> list:
+    """The run's requests: a replayed trace (``--workload``) or
+    fixed-interval arrivals at ``--rate``. Token counts are clamped to
+    the jitted model's fixed prompt/max-new dims (the executor runs
+    whole prompts)."""
     P, N = args.prompt, args.max_new
-    max_seq = P + N
+    if not args.workload:
+        interval_ms = 1000.0 / args.rate
+        return [Request(rid=i, arrive_ms=i * interval_ms, prompt_len=P,
+                        max_new=N) for i in range(args.requests)]
+    # scenario name or JSON trace path (repro.sched.workload): the trace
+    # supplies arrival times, tenants and per-tenant deadline windows
+    trace = load_trace(args.workload, seed=args.seed)
+    reqs = [Request(rid=r.rid, arrive_ms=r.arrive_ms, prompt_len=P,
+                    max_new=N, tenant=r.tenant,
+                    deadline_window_ms=r.deadline_window_ms)
+            for r in trace.requests[:args.requests]]
+    print(f"[serve] workload {args.workload!r}: {len(reqs)} requests "
+          f"replayed (of {len(trace.requests)} in the trace)")
+    return reqs
+
+
+def _compile(executors: dict) -> float:
+    compile_s = sum(ex.compile() for ex in executors.values())
+    print(f"[serve] compiled and warmed prefill+decode for "
+          f"{len(executors)} executor(s) in {compile_s:.1f}s (set-up, "
+          "outside the measured window)")
+    return compile_s
+
+
+def _print_latency(s: dict, extra: str = ""):
+    print(f"[serve] ttft_p50={s['ttft_p50_ms']:.1f}ms "
+          f"ttft_p99={s['ttft_p99_ms']:.1f}ms "
+          f"itl_p50={s['itl_p50_ms']:.1f}ms "
+          f"itl_p99={s['itl_p99_ms']:.1f}ms{extra}")
+
+
+def run_engine(args, cfg, model, params) -> ServeRun:
+    """Real-model serving through the Policy/Topology engine on the
+    first local device."""
+    P, N = args.prompt, args.max_new
     timelines, tags, src = identify_heavy_phase(model, params, args.batch,
-                                                P, max_seq, args.arch)
+                                                P, P + N, args.arch)
     heavy = _print_identification(timelines, tags, src)
     print(f"[serve] tagging {heavy!r} as the heavy (AVX-analogue) phase;"
           " SpecializedPolicy confines it to the prefill pool\n")
 
     topo = Topology.serving(n_devices=2, prefill_devices=1)
-    policy = SpecializedPolicy()
-    ex = RealModelExecutor(model, params, cfg.vocab, P, max_seq,
-                           seed=args.seed)
-    if args.workload:
-        # scenario name or JSON trace path (repro.sched.workload): the
-        # trace supplies arrival times, tenants and per-tenant deadline
-        # windows; token counts are clamped to the jitted model's fixed
-        # prompt/max-new dims (the real executor runs whole prompts)
-        trace = load_trace(args.workload, seed=args.seed)
-        reqs = [Request(rid=r.rid, arrive_ms=r.arrive_ms, prompt_len=P,
-                        max_new=N, tenant=r.tenant,
-                        deadline_window_ms=r.deadline_window_ms)
-                for r in trace.requests[:args.requests]]
-        print(f"[serve] workload {args.workload!r}: "
-              f"{len(reqs)} requests replayed "
-              f"(of {len(trace.requests)} in the trace)")
-    else:
-        interval_ms = 1000.0 / args.rate
-        reqs = [Request(rid=i, arrive_ms=i * interval_ms, prompt_len=P,
-                        max_new=N) for i in range(args.requests)]
-    eng = Engine(topo, policy,
+    ex = RealModelExecutor(model, params, cfg.vocab, P, P + N,
+                           jax.devices()[0], seed=args.seed)
+    executors = {"engine": ex}
+    compile_s = _compile(executors)
+    reqs = _requests(args)
+    eng = Engine(topo, SpecializedPolicy(),
                  cfg=ServeConfig(prefill_chunk=P,
                                  decode_batch_max=args.batch,
                                  freq=engine_freq_config(args.arch)),
                  executor=ex)
-    t0 = time.time()
+    t0 = time.perf_counter()
     m = eng.run(reqs)               # no horizon: run to completion
-    wall = time.time() - t0
+    wall = time.perf_counter() - t0
     s = m.summary()
-    total_tokens = m.completed * N
     print(f"[serve] {m.completed}/{len(reqs)} requests, "
-          f"{total_tokens} tokens in {wall:.1f}s wall")
-    print(f"[serve] ttft_p50={s['ttft_p50_ms']:.1f}ms "
-          f"ttft_p99={s['ttft_p99_ms']:.1f}ms "
-          f"itl_p50={s['itl_p50_ms']:.1f}ms "
-          f"itl_p99={s['itl_p99_ms']:.1f}ms")
+          f"{m.completed * N} tokens in {wall:.1f}s wall")
+    _print_latency(s)
     busy = ", ".join(
         "{}: heavy={:.0f}ms light={:.0f}ms".format(k, v["heavy"], v["light"])
         for k, v in m.pool_busy.items())
@@ -209,68 +309,40 @@ def run_engine(args, cfg, model, params):
             f["energy_proxy"])
         for k, f in m.pool_freq.items())
     print(f"[serve] frequency domains: {{{freq}}}")
-    return m
+    return ServeRun(m, executors, compile_s)
 
 
-def shard_contexts(n_shards: int) -> list:
-    """Partition the local devices into one ``DistContext`` per shard.
-
-    Shard ``i`` owns a contiguous slice of ``jax.devices()``; a slice
-    with more than one device becomes a data-parallel mesh
-    (``make_dist``), a single-device slice (the CPU case) runs under
-    ``no_dist()``. The cluster's shard placement therefore maps
-    directly onto dist-layer meshes: the router decides WHICH mesh a
-    request's prefill/decode executes on."""
+def shard_devices(n_shards: int) -> list:
+    """One local device per engine shard: shard ``i`` runs on
+    ``jax.devices()[i]``. Refuses more shards than devices rather than
+    stacking two shards on one device."""
     devs = jax.devices()
-    per = max(1, len(devs) // n_shards)
-    ctxs: list[DistContext] = []
-    for i in range(n_shards):
-        chunk = devs[i * per:(i + 1) * per] or devs[-1:]
-        if len(chunk) > 1:
-            from jax.sharding import Mesh
-            ctxs.append(make_dist(Mesh(np.array(chunk), ("data",))))
-        else:
-            ctxs.append(no_dist())
-    return ctxs
+    if not 1 <= n_shards <= len(devs):
+        raise ValueError(f"{n_shards} shards need as many devices; "
+                         f"{len(devs)} available")
+    return devs[:n_shards]
 
 
-def run_cluster(args, cfg, model, params):
+def run_cluster(args, cfg, model, params) -> ServeRun:
     """Real-model cluster serving: N shards, each a two-pool engine
-    with its own jitted executor on its own device slice, behind the
+    with its own jitted executor on its own device, behind the
     SLO-aware router."""
     P, N = args.prompt, args.max_new
-    max_seq = P + N
     timelines, tags, src = identify_heavy_phase(model, params, args.batch,
-                                                P, max_seq, args.arch)
+                                                P, P + N, args.arch)
     heavy = _print_identification(timelines, tags, src)
     print(f"[serve] tagging {heavy!r} as the heavy phase; "
           f"{args.shards}-shard cluster under {args.cluster_policy!r}\n")
 
     cluster = ClusterTopology.homogeneous(args.shards, 2, 1)
-    ctxs = shard_contexts(args.shards)
     executors = {}
-    for spec, ctx in zip(cluster.shards, ctxs):
-        # per-shard model bound to the shard's mesh slice; parameters
-        # are shared (same structure on every context)
-        shard_model = build_model(cfg, ctx) if ctx.active else model
+    for spec, dev in zip(cluster.shards, shard_devices(args.shards)):
         executors[spec.name] = RealModelExecutor(
-            shard_model, params, cfg.vocab, P, max_seq, seed=args.seed)
-        mesh = f"mesh={tuple(ctx.mesh.shape.values())}" if ctx.active \
-            else "single-device"
-        print(f"[serve] {spec.name}: {spec.topology.n_units} pools units, "
-              f"{mesh}")
-
-    if args.workload:
-        trace = load_trace(args.workload, seed=args.seed)
-        reqs = [Request(rid=r.rid, arrive_ms=r.arrive_ms, prompt_len=P,
-                        max_new=N, tenant=r.tenant,
-                        deadline_window_ms=r.deadline_window_ms)
-                for r in trace.requests[:args.requests]]
-        print(f"[serve] workload {args.workload!r}: {len(reqs)} requests")
-    else:
-        interval_ms = 1000.0 / args.rate
-        reqs = [Request(rid=i, arrive_ms=i * interval_ms, prompt_len=P,
-                        max_new=N) for i in range(args.requests)]
+            model, params, cfg.vocab, P, P + N, dev, seed=args.seed)
+        print(f"[serve] {spec.name}: {spec.topology.n_units} pool units "
+              f"on {dev.platform}:{dev.id}")
+    compile_s = _compile(executors)
+    reqs = _requests(args)
     ccfg = ClusterConfig(serve=ServeConfig(
         prefill_chunk=P, decode_batch_max=args.batch,
         freq=engine_freq_config(args.arch)))
@@ -282,7 +354,7 @@ def run_cluster(args, cfg, model, params):
         plan = resolve_fault_plan(args.fault_plan)
         print(f"[serve] fault plan {plan.name!r} "
               f"(hash {plan.plan_hash})")
-    t0 = time.time()
+    t0 = time.perf_counter()
     if plan is None:
         m = eng.run(reqs)           # no horizon: run to completion
     else:
@@ -291,15 +363,11 @@ def run_cluster(args, cfg, model, params):
         last_arrive = max(r.arrive_ms for r in reqs) if reqs else 0.0
         m = eng.run(reqs, last_arrive + 60_000.0, fault_plan=plan,
                     fault_horizon_ms=last_arrive)
-    wall = time.time() - t0
+    wall = time.perf_counter() - t0
     s = m.summary()
     print(f"[serve] {s['completed']}/{len(reqs)} requests in "
           f"{wall:.1f}s wall")
-    print(f"[serve] ttft_p50={s['ttft_p50_ms']:.1f}ms "
-          f"ttft_p99={s['ttft_p99_ms']:.1f}ms "
-          f"itl_p50={s['itl_p50_ms']:.1f}ms "
-          f"itl_p99={s['itl_p99_ms']:.1f}ms "
-          f"holds={s['router_holds']}")
+    _print_latency(s, f" holds={s['router_holds']}")
     if plan is not None:
         print(f"[serve] faults: injected={s['faults_injected']} "
               f"recoveries={s['shard_recoveries']} "
@@ -311,59 +379,20 @@ def run_cluster(args, cfg, model, params):
               f"done={sh['completed']} f={sh['avg_freq_ghz']:.2f}GHz "
               f"residency={sh['license_residency']:.2f} "
               f"E={sh['energy_proxy']:.0f}")
-    return m
+    return ServeRun(m, executors, compile_s)
 
 
-def run_loop(args, cfg, model, params):
-    """Plain batched loop (the pre-engine behaviour), kept for
-    comparison."""
-    B, P, N = args.batch, args.prompt, args.max_new
-    max_seq = P + N
-    timelines, tags, src = identify_heavy_phase(model, params, B, P,
-                                                max_seq, args.arch)
-    heavy = _print_identification(timelines, tags, src)
-    print(f"[serve] tagging {heavy!r} as the heavy phase\n")
-
-    prefill_j = jax.jit(lambda p, t, c: model.prefill(p, {"tokens": t}, c))
-    decode_j = jax.jit(lambda p, c, t, l: model.decode_step(p, c, t, l))
-    rng = np.random.default_rng(args.seed)
-    n_batches = (args.requests + B - 1) // B
-    t0 = time.time()
-    total_tokens = 0
-    for bi in range(n_batches):
-        prompts = jnp.asarray(rng.integers(0, cfg.vocab, size=(B, P)),
-                              dtype=jnp.int32)
-        cache = model.init_cache(params, {"tokens": prompts}, B, max_seq)
-        tp0 = time.time()
-        logits, cache = prefill_j(params, prompts, cache)
-        logits.block_until_ready()
-        ttft = time.time() - tp0
-        lengths = jnp.full((B,), P, jnp.int32)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-        itl = []
-        for _ in range(N - 1):
-            td0 = time.time()
-            logits, cache = decode_j(params, cache, tok, lengths)
-            logits.block_until_ready()
-            itl.append(time.time() - td0)
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-            lengths = lengths + 1
-        total_tokens += B * N
-        print(f"[serve] batch {bi}: ttft={ttft*1e3:.1f}ms "
-              f"itl_p50={np.median(itl)*1e3:.1f}ms "
-              f"itl_max={max(itl)*1e3:.1f}ms")
-    dt_ = time.time() - t0
-    print(f"[serve] {total_tokens} tokens in {dt_:.1f}s "
-          f"({total_tokens/dt_:.0f} tok/s)")
-
-
-def main(argv=None):
+def main(argv=None) -> ServeRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
-    ap.add_argument("--mode", choices=("engine", "loop", "cluster"),
+    ap.add_argument("--reduced", action="store_true",
+                    help="use the smoke-test-sized config (CPU runs); "
+                         "default: the published width")
+    ap.add_argument("--mode", choices=("engine", "cluster"),
                     default="engine")
     ap.add_argument("--shards", type=int, default=2,
-                    help="cluster mode: number of engine shards")
+                    help="cluster mode: number of engine shards, one "
+                         "device each")
     ap.add_argument("--cluster-policy", default="cluster-adaptive",
                     help="cluster mode: registered cluster policy "
                          "(cluster-rr, cluster-queue, cluster-freq, "
@@ -386,15 +415,16 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    cfg = get_arch(args.arch).reduced()
+    enable_compile_cache()
+    cfg = get_arch(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    print(f"[serve] {cfg.name}: {cfg.n_layers} layers, d_model "
+          f"{cfg.d_model}, vocab {cfg.vocab}, {cfg.param_dtype}")
     model = build_model(cfg, no_dist())
-    params = model.init(jax.random.key(args.seed))
-    if args.mode == "engine":
-        run_engine(args, cfg, model, params)
-    elif args.mode == "cluster":
-        run_cluster(args, cfg, model, params)
-    else:
-        run_loop(args, cfg, model, params)
+    params = jax.jit(model.init)(jax.random.key(args.seed))
+    run = run_cluster if args.mode == "cluster" else run_engine
+    return run(args, cfg, model, params)
 
 
 if __name__ == "__main__":

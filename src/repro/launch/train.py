@@ -17,6 +17,7 @@ import jax
 from repro.configs import get_arch
 from repro.data.pipeline import DataConfig, DataState, Pipeline
 from repro.dist.context import no_dist
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.train.checkpoint import CheckpointManager
 from repro.train.elastic import Watchdog, install_preemption_handler
@@ -44,6 +45,7 @@ def main(argv=None):
                     help="override layer count (0 = config value)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-import repro.dist  # noqa: F401  (installs the jax.shard_map compat shim)
 from repro.configs.base import ArchConfig
 from repro.models.layers import ACTS, dt
 

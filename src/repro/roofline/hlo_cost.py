@@ -465,10 +465,6 @@ def analyze(text: str, default_group: int = 1) -> CostTotals:
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` across JAX versions: older releases
-    return a single-element list of per-property dicts, newer ones the
-    dict itself. Always returns a (possibly empty) dict."""
+    """``Compiled.cost_analysis()`` as a (possibly empty) dict."""
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     return dict(cost) if cost is not None else {}

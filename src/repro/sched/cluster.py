@@ -36,9 +36,8 @@ Shard engines never self-resize in cluster mode (their
 only observer, so the §4.3 estimator sees clean, non-overlapping
 windows per shard.
 
-Real-model mode (`launch/serve.py --mode cluster`) maps each shard onto
-its own ``repro.dist.DistContext`` mesh slice so jitted prefill/decode
-executors run per-shard; the simulated mode used here prices work
+Real-model mode (`launch/serve.py --mode cluster`) runs each shard's
+jitted prefill/decode executor on its own device; the simulated mode used here prices work
 through the shared :class:`PoolModel` exactly like the single-node
 engine, so cluster runs replay deterministically under the oracle.
 """

@@ -9,6 +9,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist.context import make_dist, no_dist
 from repro.dist.sharding import sanitize_spec, sanitize_specs, tree_shardings
+from repro.launch.mesh import auto_mesh
 
 
 class FakeMesh:
@@ -22,7 +23,7 @@ class FakeMesh:
 @pytest.fixture(scope="module")
 def mesh():
     # 1x1 mesh: axis *names* drive sanitation, sizes are all 1
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def test_sanitize_drops_axis_missing_from_mesh(mesh):
@@ -108,7 +109,7 @@ def test_make_dist_ep_over_dp(mesh):
 
 
 def test_make_dist_pure_dp_mesh():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = auto_mesh((1,), ("data",))
     d = make_dist(mesh)
     assert d.model_axis is None and d.ep_axes == ()
     assert d.ep_size == 1 and d.model_size == 1

@@ -1,5 +1,6 @@
 """Per-kernel validation: RFC test vector, ref-oracle allclose, and
-hypothesis shape/dtype sweeps (interpret=True executes the kernel body)."""
+hypothesis shape/dtype sweeps. Every call passes ``interpret=True``: the
+kernels default to compiled mode, which only a TPU can lower."""
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -23,7 +24,7 @@ RFC_BLOCK1 = bytes.fromhex(
 
 def test_chacha20_rfc7539_vector():
     ks = keystream(jnp.asarray(RFC_KEY), jnp.asarray(RFC_NONCE), 1,
-                   n_blocks=4, tile=4)
+                   n_blocks=4, tile=4, interpret=True)
     got = np.asarray(ks[0]).astype("<u4").tobytes()
     assert got == RFC_BLOCK1
 
@@ -31,7 +32,7 @@ def test_chacha20_rfc7539_vector():
 def test_chacha20_matches_ref_many_blocks():
     key = jnp.arange(8, dtype=jnp.uint32) * 0x01010101
     nonce = jnp.asarray([7, 11, 13], dtype=jnp.uint32)
-    ks = keystream(key, nonce, 42, n_blocks=512, tile=128)
+    ks = keystream(key, nonce, 42, n_blocks=512, tile=128, interpret=True)
     want = ref.chacha20_keystream_ref(key, nonce, 42, 512)
     np.testing.assert_array_equal(np.asarray(ks), np.asarray(want))
 
@@ -43,7 +44,7 @@ def test_chacha20_property_counter_and_tiles(ctr, tiles):
         0, 2**31, size=8), dtype=jnp.uint32)
     nonce = jnp.asarray([1, 2, 3], dtype=jnp.uint32)
     n = 16 * tiles
-    ks = keystream(key, nonce, ctr, n_blocks=n, tile=16)
+    ks = keystream(key, nonce, ctr, n_blocks=n, tile=16, interpret=True)
     want = ref.chacha20_keystream_ref(key, nonce, ctr, n)
     np.testing.assert_array_equal(np.asarray(ks), np.asarray(want))
 
@@ -68,7 +69,8 @@ def _mk_qkv(key, B, H, KVH, S, D, dtype):
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention_allclose(B, H, KVH, S, D, dtype, causal):
     q, k, v = _mk_qkv(jax.random.key(0), B, H, KVH, S, D, dtype)
-    got = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    got = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
+                          interpret=True)
     want = ref.attention_ref(q, k, v, causal=causal)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -83,7 +85,8 @@ def test_flash_attention_property(S, D, G, causal):
     KVH = 2
     q, k, v = _mk_qkv(jax.random.key(S * D * G), 1, KVH * G, KVH, S, D,
                       jnp.float32)
-    got = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    got = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
+                          interpret=True)
     want = ref.attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -103,7 +106,7 @@ def test_flash_decode_allclose(B, H, KVH, S, D, dtype):
     k = jax.random.normal(ks[1], (B, KVH, S, D)).astype(dtype)
     v = jax.random.normal(ks[2], (B, KVH, S, D)).astype(dtype)
     lengths = jnp.asarray([S // 2, S, 7][:B][:B] + [S] * max(0, B - 3))[:B]
-    got = flash_decode(q, k, v, lengths, block_k=128)
+    got = flash_decode(q, k, v, lengths, block_k=128, interpret=True)
     want = ref.decode_attention_ref(q, k, v, lengths)
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -121,7 +124,7 @@ def test_flash_decode_property_lengths(B, S, D, length):
     k = jax.random.normal(ks[1], (B, 2, S, D))
     v = jax.random.normal(ks[2], (B, 2, S, D))
     lengths = jnp.full((B,), length, jnp.int32)
-    got = flash_decode(q, k, v, lengths, block_k=64)
+    got = flash_decode(q, k, v, lengths, block_k=64, interpret=True)
     want = ref.decode_attention_ref(q, k, v, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)

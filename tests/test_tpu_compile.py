@@ -1,0 +1,106 @@
+"""Compile the serve path's programs for a described TPU v5e.
+
+No chip is attached: XLA's TPU compiler builds each program for
+``v5e:2x2`` from shapes alone, and refuses what the chip would refuse
+(tiling, scoped memory, a program larger than the device). The
+topology is described inside a fixture, never while a module is
+imported, so every test worker collects the same tests and only the one
+that runs this file loads the TPU library. The persistent compilation
+cache is off around these compiles: an entry compiled for a described
+device cannot be read back without one.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_arch
+from repro.dist.context import no_dist
+from repro.kernels.chacha20 import keystream
+from repro.kernels.decode_attention import flash_decode
+from repro.kernels.flash_attention import flash_attention
+from repro.launch.serve import placed, serve_steps
+from repro.models.api import build_model
+
+HBM_BYTES = 16 * 2**30          # one v5e chip
+PROMPT, MAX_SEQ = 1024, 1152     # qwen1.5-0.5b serve shapes, batch 1
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def qwen(one_chip):
+    """qwen1.5-0.5b at its published width: the serve steps, and its
+    parameters and prompt as shapes on one described chip."""
+    model = build_model(get_arch("qwen1.5-0.5b"), no_dist())
+    params = jax.tree.map(lambda s: placed(s, one_chip),
+                          model.abstract_params())
+    toks = placed(jax.ShapeDtypeStruct((1, PROMPT), jnp.int32), one_chip)
+    return serve_steps(model, MAX_SEQ), params, toks
+
+
+def _fits(compiled):
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 0 < used < HBM_BYTES, used
+
+
+def test_qwen_prefill_compiles(qwen):
+    (prefill, _), params, toks = qwen
+    _fits(jax.jit(prefill).lower(params, toks).compile())
+
+
+def test_qwen_decode_step_compiles(qwen, one_chip):
+    (prefill, decode), params, toks = qwen
+    tok, _, cache, lengths = jax.tree.map(
+        lambda s: placed(s, one_chip), jax.eval_shape(prefill, params, toks))
+    _fits(jax.jit(decode).lower(params, cache, tok, lengths).compile())
+
+
+def _kernel_hlo(fn, *shapes, sharding):
+    args = [placed(s, sharding) for s in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_flash_attention_compiles(one_chip):
+    q = jax.ShapeDtypeStruct((1, 16, 2048, 64), jnp.bfloat16)
+    hlo = _kernel_hlo(lambda q, k, v: flash_attention(q, k, v), q, q, q,
+                      sharding=one_chip)
+    assert "tpu_custom_call" in hlo
+
+
+def test_flash_decode_compiles(one_chip):
+    q = jax.ShapeDtypeStruct((8, 16, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((8, 16, 2048, 64), jnp.bfloat16)
+    lengths = jax.ShapeDtypeStruct((8,), jnp.int32)
+    hlo = _kernel_hlo(lambda q, k, v, n: flash_decode(q, k, v, n),
+                      q, kv, kv, lengths, sharding=one_chip)
+    assert "tpu_custom_call" in hlo
+
+
+def test_chacha20_compiles(one_chip):
+    key = jax.ShapeDtypeStruct((8,), jnp.uint32)
+    nonce = jax.ShapeDtypeStruct((3,), jnp.uint32)
+    hlo = _kernel_hlo(
+        lambda k, n: keystream(k, n, 1, n_blocks=4096, tile=256),
+        key, nonce, sharding=one_chip)
+    assert "tpu_custom_call" in hlo
