@@ -71,6 +71,16 @@ def placed(s, sharding):
     return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding)
 
 
+def _call(fn, *args):
+    """``fn(*args)``, a compiled step, once its token is ready: the call
+    annotated ``executor.dispatch``, the wait ``executor.sync``."""
+    with jax.profiler.TraceAnnotation("executor.dispatch"):
+        out = fn(*args)
+    with jax.profiler.TraceAnnotation("executor.sync"):
+        out[0].block_until_ready()
+    return out
+
+
 class RealModelExecutor:
     """Engine executor that runs real jitted prefill/decode steps on one
     device.
@@ -84,6 +94,12 @@ class RealModelExecutor:
     ``rid``'s prompt is drawn from ``(seed, rid)``, so it does not depend
     on which executor serves it or in what order. Every emitted token is
     recorded in ``tokens[rid]``.
+
+    Each prompt upload, step dispatch and wait for a step's token is a
+    ``jax.profiler.TraceAnnotation`` (``executor.upload``,
+    ``executor.dispatch``, ``executor.sync``): while the profiler runs,
+    the host's time in a call shows on the trace's host plane, beside
+    the device's programs.
     """
 
     def __init__(self, model, params, vocab: int, prompt_len: int,
@@ -132,10 +148,10 @@ class RealModelExecutor:
         # same request are free — total charged time stays the real cost
         if req.rid in self.state:
             return 0.0
-        toks = jax.device_put(self.prompt(req.rid), self.device)
+        with jax.profiler.TraceAnnotation("executor.upload"):
+            toks = jax.device_put(self.prompt(req.rid), self.device)
         t0 = time.perf_counter()
-        tok, ok, cache, lengths = self.prefill_j(self.params, toks)
-        tok.block_until_ready()
+        tok, ok, cache, lengths = _call(self.prefill_j, self.params, toks)
         dur_ms = (time.perf_counter() - t0) * 1e3
         self.state[req.rid] = (cache, tok, lengths)
         self.tokens[req.rid] = [tok]
@@ -146,9 +162,8 @@ class RealModelExecutor:
         t0 = time.perf_counter()
         for req in batch:
             cache, tok, lengths = self.state.pop(req.rid)
-            tok, ok, cache, lengths = self.decode_j(self.params, cache, tok,
-                                                    lengths)
-            tok.block_until_ready()
+            tok, ok, cache, lengths = _call(self.decode_j, self.params,
+                                            cache, tok, lengths)
             self.tokens[req.rid].append(tok)
             self._finite.append(ok)
             # a request that finishes with this token drops its KV cache,
@@ -303,12 +318,6 @@ def run_engine(args, cfg, model, params) -> ServeRun:
         for k, v in m.pool_busy.items())
     print(f"[serve] handoffs={s['handoffs']} steals={s['steals']} "
           f"pool_busy={{{busy}}}")
-    freq = ", ".join(
-        "{}: f={:.2f}GHz reduced={:.0f}ms transitions={} E={:.0f}".format(
-            k, f["avg_freq_ghz"], f["reduced"], f["transitions"],
-            f["energy_proxy"])
-        for k, f in m.pool_freq.items())
-    print(f"[serve] frequency domains: {{{freq}}}")
     return ServeRun(m, executors, compile_s)
 
 
@@ -376,9 +385,7 @@ def run_cluster(args, cfg, model, params) -> ServeRun:
               f"expired={s['expired_total']}")
     for name, sh in m.shard_summaries().items():
         print(f"[serve]   {name}: routed={sh['routed']} "
-              f"done={sh['completed']} f={sh['avg_freq_ghz']:.2f}GHz "
-              f"residency={sh['license_residency']:.2f} "
-              f"E={sh['energy_proxy']:.0f}")
+              f"done={sh['completed']}")
     return ServeRun(m, executors, compile_s)
 
 
