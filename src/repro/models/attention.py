@@ -5,7 +5,10 @@ Each block exposes:
   forward(params, x, cfg, positions) -> y                  (full sequence)
   init_cache(cfg, batch, max_seq, dtype) -> cache
   prefill(params, x, cfg, cache, positions) -> (y, cache)  (writes cache)
-  decode(params, x, cfg, cache, lengths) -> (y, cache)     (x is [B,1,d])
+  decode_token(params, x, cfg, cache, lengths) -> (y, new) (x is [B,1,d];
+      ``new`` is the token's cache entries, which the caller writes)
+  decode(params, x, cfg, cache, lengths) -> (y, cache)     (GQA: the same,
+      written into one layer's cache, for the hybrid and enc-dec models)
 
 MLA caches the compressed latent (c_kv + k_rope) and uses the absorbed
 matmul form for decode (W_uk folded into q, W_uv applied post-attention),
@@ -23,7 +26,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig, MLAConfig
 from repro.models.layers import (
     apply_rope, chunked_attention, decode_attention, dense, dt, init_dense,
-    rmsnorm,
+    rmsnorm, softmax_with_token,
 )
 
 # =========================================================== GQA attention
@@ -91,17 +94,38 @@ def gqa_prefill(p, x, cfg: ArchConfig, cache, positions):
     return dense(p["wo"], o.reshape(B, S, -1), cdt), cache
 
 
-def gqa_decode(p, x, cfg: ArchConfig, cache, lengths):
-    """x: [B,1,d]; lengths[b] = number of tokens BEFORE this one."""
+def write_tokens(cache, new, lengths, stacked: bool = False):
+    """``cache`` with row b of each buffer set at position ``lengths[b]``
+    to row b of ``new``'s, and nothing else changed. The buffers are one
+    layer's ``[B,S,...]`` with ``new`` ``[B,...]``, or, ``stacked``,
+    every layer's ``[L,B,S,...]`` with ``new`` ``[L,B,...]``."""
+    at = (jnp.arange(lengths.shape[0]), lengths)
+    if stacked:
+        at = (slice(None), *at)
+    return {k: buf.at[at].set(new[k].astype(buf.dtype))
+            for k, buf in cache.items()}
+
+
+def gqa_decode_token(p, x, cfg: ArchConfig, cache, lengths):
+    """x: [B,1,d]; lengths[b] = number of tokens BEFORE this one. Attends
+    over the cache's first ``lengths[b]`` positions and the token itself,
+    and returns ``(y, new)``: the token's ``{"k", "v"}`` ``[B,KVH,D]``,
+    which it leaves to the caller to write (``write_tokens``)."""
     B = x.shape[0]
     cdt = dt(cfg.compute_dtype)
     positions = lengths[:, None]                            # [B,1]
     q, k, v = _qkv(p, x, cfg, positions)
-    bidx = jnp.arange(B)
-    kc = cache["k"].at[bidx, lengths, :, :].set(k[:, 0].astype(cache["k"].dtype))
-    vc = cache["v"].at[bidx, lengths, :, :].set(v[:, 0].astype(cache["v"].dtype))
-    o = decode_attention(q, kc, vc, lengths + 1, compute_dtype=cdt)
-    return dense(p["wo"], o.reshape(B, 1, -1), cdt), {"k": kc, "v": vc}
+    new = {"k": k[:, 0].astype(cache["k"].dtype),
+           "v": v[:, 0].astype(cache["v"].dtype)}
+    o = decode_attention(q, cache["k"], cache["v"], lengths,
+                         k_new=new["k"], v_new=new["v"], compute_dtype=cdt)
+    return dense(p["wo"], o.reshape(B, 1, -1), cdt), new
+
+
+def gqa_decode(p, x, cfg: ArchConfig, cache, lengths):
+    """``gqa_decode_token`` on one layer's cache, with the token written."""
+    y, new = gqa_decode_token(p, x, cfg, cache, lengths)
+    return y, write_tokens(cache, new, lengths)
 
 
 # =========================================================== MLA attention
@@ -190,8 +214,11 @@ def _mla_wkv_b_split(p, cfg):
     return w[..., :m.nope_head_dim], w[..., m.nope_head_dim:]  # [lora,H,nope],[lora,H,v]
 
 
-def mla_decode(p, x, cfg: ArchConfig, cache, lengths):
-    """Absorbed-form decode: score/readout in the compressed latent space."""
+def mla_decode_token(p, x, cfg: ArchConfig, cache, lengths):
+    """Absorbed-form decode: score/readout in the compressed latent space,
+    over the cache's first ``lengths[b]`` positions and the token itself.
+    Returns ``(y, new)`` as ``gqa_decode_token`` does, ``new`` the token's
+    ``{"c_kv": [B,lora], "k_rope": [B,rope]}``."""
     m = cfg.mla
     B = x.shape[0]
     H = cfg.n_heads
@@ -199,28 +226,35 @@ def mla_decode(p, x, cfg: ArchConfig, cache, lengths):
     positions = lengths[:, None]
     q_nope, q_rope = _mla_q(p, x, cfg, positions)           # [B,1,H,*]
     c_kv_new, k_rope_new = _mla_latent(p, x, cfg, positions)
-    bidx = jnp.arange(B)
-    ckv = cache["c_kv"].at[bidx, lengths, :].set(c_kv_new[:, 0].astype(cache["c_kv"].dtype))
-    krp = cache["k_rope"].at[bidx, lengths, :].set(k_rope_new[:, 0].astype(cache["k_rope"].dtype))
+    ckv, krp = cache["c_kv"], cache["k_rope"]
+    new = {"c_kv": c_kv_new[:, 0].astype(ckv.dtype),
+           "k_rope": k_rope_new[:, 0].astype(krp.dtype)}
     w_uk, w_uv = _mla_wkv_b_split(p, cfg)
     # absorb W_uk into q: q_lat [B,1,H,lora]
     q_lat = jnp.einsum("bshn,lhn->bshl", q_nope.astype(cdt), w_uk.astype(cdt),
                        preferred_element_type=jnp.float32)
+
+    def scores(c, r):                                       # -> [B,H,1,T]
+        return (jnp.einsum("bshl,btl->bhst", q_lat.astype(cdt), c.astype(cdt),
+                           preferred_element_type=jnp.float32)
+                + jnp.einsum("bshr,btr->bhst", q_rope.astype(cdt),
+                             r.astype(cdt), preferred_element_type=jnp.float32)
+                ) / math.sqrt(m.nope_head_dim + m.rope_head_dim)
+
+    def readout(pattn, c):                                  # -> [B,1,H,lora]
+        return jnp.einsum("bhst,btl->bshl", pattn.astype(cdt), c.astype(cdt),
+                          preferred_element_type=jnp.float32)
+
     Smax = ckv.shape[1]
-    s = (jnp.einsum("bshl,btl->bhst", q_lat.astype(cdt), ckv.astype(cdt),
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("bshr,btr->bhst", q_rope.astype(cdt), krp.astype(cdt),
-                      preferred_element_type=jnp.float32))
-    s = s / math.sqrt(m.nope_head_dim + m.rope_head_dim)
-    valid = (jnp.arange(Smax)[None, :] < (lengths + 1)[:, None])[:, None, None, :]
-    s = jnp.where(valid, s, -1e30)
-    pattn = jax.nn.softmax(s, axis=-1)                      # [B,H,1,Smax]
-    o_lat = jnp.einsum("bhst,btl->bshl", pattn.astype(cdt), ckv.astype(cdt),
-                       preferred_element_type=jnp.float32)  # [B,1,H,lora]
+    valid = (jnp.arange(Smax)[None, :] < lengths[:, None])[:, None, None, :]
+    s = jnp.where(valid, scores(ckv, krp), -1e30)
+    s_new = scores(new["c_kv"][:, None], new["k_rope"][:, None])
+    pattn, p_new = softmax_with_token(s, s_new)
+    o_lat = readout(pattn, ckv) + readout(p_new, new["c_kv"][:, None])
     o = jnp.einsum("bshl,lhv->bshv", o_lat.astype(cdt), w_uv.astype(cdt),
                    preferred_element_type=jnp.float32)      # [B,1,H,v]
     y = dense(p["wo"], o.reshape(B, 1, H * m.v_head_dim).astype(cdt), cdt)
-    return y, {"c_kv": ckv, "k_rope": krp}
+    return y, new
 
 
 def mla_decode_naive(p, x, cfg: ArchConfig, cache, lengths):

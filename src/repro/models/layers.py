@@ -219,12 +219,25 @@ def full_attention(q, k, v, *, causal, q_positions=None, kv_positions=None,
     return o.reshape(B, Sq, H, Dv).astype(q.dtype)
 
 
-def decode_attention(q, k_cache, v_cache, lengths, *, scale=None,
-                     compute_dtype=jnp.bfloat16):
+def softmax_with_token(s, s_new):
+    """Softmax over ``s [..., S]`` with one more column ``s_new [..., 1]``
+    after it, as the two parts ``(p [..., S], p_new [..., 1])``: a token's
+    attention over a cache and over itself, without joining the two."""
+    m = jnp.maximum(s.max(axis=-1, keepdims=True), s_new)
+    e, e_new = jnp.exp(s - m), jnp.exp(s_new - m)
+    z = e.sum(axis=-1, keepdims=True) + e_new
+    return e / z, e_new / z
+
+
+def decode_attention(q, k_cache, v_cache, lengths, *, k_new=None, v_new=None,
+                     scale=None, compute_dtype=jnp.bfloat16):
     """One-token attention against a KV cache.
 
     q: [B, 1, H, D]; k/v_cache: [B, Smax, KVH, D*]; lengths: [B] valid length
-    (the new token's position is lengths-1 after cache insert).
+    (the new token's position is lengths-1 after cache insert). With
+    ``k_new``/``v_new`` [B, KVH, D*], the token's own key and value not yet
+    in the cache, it attends over the cache's first ``lengths`` positions
+    and over itself.
     """
     B, _, H, Dq = q.shape
     Smax, KVH = k_cache.shape[1], k_cache.shape[2]
@@ -234,9 +247,18 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale=None,
     s = _gqa_scores(qg, k_cache, compute_dtype) * scale    # [B,KVH,G,1,Smax]
     valid = jnp.arange(Smax)[None, :] < lengths[:, None]   # [B,Smax]
     s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgst,btkd->bskgd", p.astype(compute_dtype),
-                   v_cache.astype(compute_dtype), preferred_element_type=jnp.float32)
+
+    def readout(p, v):
+        return jnp.einsum("bkgst,btkd->bskgd", p.astype(compute_dtype),
+                          v.astype(compute_dtype),
+                          preferred_element_type=jnp.float32)
+
+    if k_new is None:
+        o = readout(jax.nn.softmax(s, axis=-1), v_cache)
+    else:
+        s_new = _gqa_scores(qg, k_new[:, None], compute_dtype) * scale
+        p, p_new = softmax_with_token(s, s_new)
+        o = readout(p, v_cache) + readout(p_new, v_new[:, None])
     return o.reshape(B, 1, H, v_cache.shape[-1]).astype(q.dtype)
 
 

@@ -295,32 +295,37 @@ def lm_prefill(params, tokens, cfg: ArchConfig, cache,
 
 def lm_decode_step(params, cache, tokens, lengths, cfg: ArchConfig,
                    dist: DistContext = no_dist()):
-    """tokens [B,1], lengths [B] -> (logits [B,V], cache)."""
-    B = tokens.shape[0]
+    """tokens [B,1], lengths [B] -> (logits [B,V], cache).
+
+    The layer loop only reads the cache: each layer attends over its slice
+    and over the new token, and hands the token's entries out of the loop,
+    which writes all layers' at once at ``(:, b, lengths[b])``. A caller
+    that donates the cache so has it updated in place, not copied."""
     cdt = dt(cfg.compute_dtype)
     x = jnp.take(params["embed"], tokens, axis=0).astype(cdt)
+    decode = (attn.mla_decode_token if cfg.attention == "mla"
+              else attn.gqa_decode_token)
 
-    def body(carry, sl):
-        x, = carry
-        p_l, cache_l = sl
+    def body(x, sl):
+        p_l, layer = sl
         h = apply_norm(p_l["norm1"], x, cfg.norm)
-        if cfg.attention == "mla":
-            y, cache_l = attn.mla_decode(p_l["attn"], h, cfg, cache_l, lengths)
-        else:
-            y, cache_l = attn.gqa_decode(p_l["attn"], h, cfg, cache_l, lengths)
+        cache_l = {k: c[layer] for k, c in cache.items()}
+        y, new = decode(p_l["attn"], h, cfg, cache_l, lengths)
         x = x + y
         h = apply_norm(p_l["norm2"], x, cfg.norm)
         if cfg.moe is not None:
             y, _ = moe_block(p_l["moe"], h, cfg, dist, dispatch="replicated" if dist.active else "auto")
         else:
             y = mlp(p_l["mlp"], h, cfg.act, cfg.glu, cdt)
-        return (x + y,), cache_l
+        return x + y, new
 
-    (x,), new_cache = jax.lax.scan(body, (x,), (params["layers"], cache))
+    x, new = jax.lax.scan(body, x, (params["layers"],
+                                    jnp.arange(cfg.n_layers)))
     x = apply_norm(params["final_norm"], x, cfg.norm)
     w = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = unembed(x, w, cdt)
-    return logits[:, 0, :], new_cache
+    return logits[:, 0, :], attn.write_tokens(cache, new, lengths,
+                                              stacked=True)
 
 
 # ------------------------------------------------- optional: MTP head

@@ -3,6 +3,7 @@ forward/loss/prefill/decode on CPU; shape + finiteness + decode-vs-
 forward consistency for every family."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import arch_ids, get_arch
@@ -79,6 +80,45 @@ def test_decode_matches_teacher_forcing(arch):
         ref, _ = hybrid.hybrid_forward(params, toks, cfg)
     err = float(jnp.abs(lg_dec - ref[:, S]).max())
     assert err < 5e-4, err
+
+
+@pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "starcoder2-15b",
+                                  "deepseek-v3-671b"])
+def test_decode_writes_each_row_at_its_own_length(arch):
+    """Two rows prefilled to different lengths, then decoded together:
+    each row's logits are its own teacher-forced ones, and each step
+    changes each row's cache at that row's position alone."""
+    from repro.models import transformer
+    cfg = get_arch(arch).reduced()
+    model = build_model(cfg, no_dist())
+    params = model.init(jax.random.key(0))
+    lens, steps, max_seq = (9, 14), 4, 32
+    toks = jax.random.randint(jax.random.key(1), (2, max(lens) + steps), 0,
+                              cfg.vocab)
+    rows = []
+    for b, n in enumerate(lens):
+        batch = {"tokens": toks[b:b + 1, :n]}
+        rows.append(model.prefill(params, batch,
+                                  model.init_cache(params, batch, 1,
+                                                   max_seq))[1])
+    cache = jax.tree.map(lambda *r: jnp.concatenate(r, axis=1), *rows)
+    ref = [transformer.lm_forward(params, toks[b:b + 1, :n + steps], cfg)[0]
+           for b, n in enumerate(lens)]
+    lengths = jnp.asarray(lens, jnp.int32)
+    for i in range(steps):
+        tok = jnp.stack([toks[b, n + i] for b, n in enumerate(lens)])[:, None]
+        lg, new = model.decode_step(params, cache, tok, lengths)
+        for b, n in enumerate(lens):
+            err = float(jnp.abs(lg[b] - ref[b][0, n + i]).max())
+            assert err < 5e-4, (b, i, err)
+        for before, after in zip(jax.tree.leaves(cache), jax.tree.leaves(new)):
+            # [L,B,S,...] -> where each (row, position) changed
+            changed = np.asarray(jnp.any(before != after, axis=tuple(
+                d for d in range(before.ndim) if d not in (1, 2))))
+            want = np.zeros_like(changed)
+            want[np.arange(2), np.asarray(lengths)] = True
+            np.testing.assert_array_equal(changed, want)
+        cache, lengths = new, lengths + 1
 
 
 def test_grad_flows_everywhere():

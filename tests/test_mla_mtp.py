@@ -30,7 +30,7 @@ def test_mla_absorbed_decode_matches_naive(setup):
     _, cache_b = attn.mla_prefill(p, warm, cfg, cache_b,
                                   jnp.arange(S)[None].repeat(B, 0))
     lengths = jnp.full((B,), S, jnp.int32)
-    y_abs, _ = attn.mla_decode(p, x, cfg, cache_a, lengths)
+    y_abs, _ = attn.mla_decode_token(p, x, cfg, cache_a, lengths)
     y_naive, _ = attn.mla_decode_naive(p, x, cfg, cache_b, lengths)
     np.testing.assert_allclose(np.asarray(y_abs), np.asarray(y_naive),
                                rtol=2e-4, atol=2e-4)

@@ -14,12 +14,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from helpers import assert_updates_cache_in_place
 from repro.configs import get_arch
 from repro.dist.context import no_dist
 from repro.kernels.chacha20 import keystream
 from repro.kernels.decode_attention import flash_decode
 from repro.kernels.flash_attention import flash_attention
-from repro.launch.serve import placed, serve_steps
+from repro.launch.serve import jit_steps, placed, serve_steps
 from repro.models.api import build_model
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
@@ -70,10 +71,15 @@ def test_qwen_prefill_compiles(qwen):
 
 
 def test_qwen_decode_step_compiles(qwen, one_chip):
-    (prefill, decode), params, toks = qwen
+    """Decode as the executor compiles it: the cache it donates is its
+    output's buffer, and neither it nor a layer's slice is copied."""
+    steps, params, toks = qwen
+    prefill, decode = jit_steps(steps)
     tok, _, cache, lengths = jax.tree.map(
         lambda s: placed(s, one_chip), jax.eval_shape(prefill, params, toks))
-    _fits(jax.jit(decode).lower(params, cache, tok, lengths).compile())
+    compiled = decode.lower(params, cache, tok, lengths).compile()
+    _fits(compiled)
+    assert_updates_cache_in_place(compiled, cache)
 
 
 def _kernel_hlo(fn, *shapes, sharding):
