@@ -13,12 +13,29 @@ from typing import Optional
 
 @dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts. The router scores all ``n_experts``; a device that
+    holds only some of them (``n_held``, a contiguous block from
+    ``held_first``) computes their part of the layer's result.
+
+    ``scoring`` "softmax" picks the top-k of the softmax. "sigmoid" is
+    DeepSeek-V3's ``noaux_tc``: a per-expert correction ``bias`` is added
+    to the sigmoid scores for choosing only; the experts fall into
+    ``n_group`` groups, a group scores the sum of its top-2 biased scores,
+    and the top-k experts are taken from the best ``topk_group`` groups.
+    The chosen experts' weights are their unbiased scores, normalised
+    over all k chosen, then scaled by ``routed_scaling``."""
     n_experts: int                 # routed experts
     top_k: int
     n_shared: int = 0              # shared (always-on) experts
     d_ff: int = 0                  # per-expert hidden size (0 -> arch d_ff)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # sharded dispatch only; held is dropless
     router_dtype: str = "float32"
+    scoring: str = "softmax"       # softmax | sigmoid
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+    n_held: int = 0                # experts held on this device (0 = all)
+    held_first: int = 0            # first expert of the held block
 
 
 @dataclass(frozen=True)
@@ -29,6 +46,18 @@ class MLAConfig:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V3 publishes
+    it (``rope_scaling`` of type ``yarn``)."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -75,12 +104,15 @@ class ArchConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnConfig] = None
+    norm_eps: float = 1e-5         # RMSNorm / LayerNorm epsilon
     act: str = "silu"              # silu | gelu
     glu: bool = True               # gated MLP (SwiGLU/GeGLU) vs plain 2-layer MLP
     norm: str = "rmsnorm"          # rmsnorm | layernorm
     tie_embeddings: bool = False
     attention: str = "gqa"         # gqa | mla | none
     moe: Optional[MoEConfig] = None
+    first_k_dense: int = 0         # leading layers with a dense MLP of d_ff
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     rwkv: Optional[RWKVConfig] = None
@@ -126,7 +158,8 @@ class ArchConfig:
         if self.moe is not None:
             e_ff = self.moe.d_ff or ff
             per_expert = d * e_ff * (3 if self.glu else 2)
-            mlp = (self.moe.n_experts + self.moe.n_shared) * per_expert \
+            held = self.moe.n_held or self.moe.n_experts
+            mlp = (held + self.moe.n_shared) * per_expert \
                 + d * self.moe.n_experts  # router
         else:
             mlp = d * ff * (3 if self.glu else 2)
@@ -158,7 +191,9 @@ class ArchConfig:
             dec = self.n_layers * (2 * attn + mlp + 3 * d)
             n += enc + dec
             return n
-        n += n_l * per_layer
+        k = self.first_k_dense
+        dense = attn + d * ff * (3 if self.glu else 2) + 2 * d
+        n += k * dense + (n_l - k) * per_layer
         return n
 
     def active_param_count(self) -> int:
@@ -167,7 +202,9 @@ class ArchConfig:
             return self.param_count()
         e_ff = self.moe.d_ff or self.d_ff
         per_expert = self.d_model * e_ff * (3 if self.glu else 2)
-        inactive = (self.moe.n_experts - self.moe.top_k) * per_expert * self.n_layers
+        held = self.moe.n_held or self.moe.n_experts
+        inactive = (held - self.moe.top_k * held / self.moe.n_experts) \
+            * per_expert * (self.n_layers - self.first_k_dense)
         return self.param_count() - inactive
 
     def reduced(self) -> "ArchConfig":
@@ -186,8 +223,18 @@ class ArchConfig:
             compute_dtype="float32",
         )
         if self.moe is not None:
-            kw["moe"] = MoEConfig(n_experts=4, top_k=2, n_shared=self.moe.n_shared,
-                                  d_ff=64, capacity_factor=2.0)
+            # grouped routing keeps its groups: 8 experts in 4 groups of 2,
+            # the best 2 groups; a held block keeps its share of the experts
+            grouped = self.moe.n_group > 1
+            n_exp = 8 if grouped else 4
+            kw["moe"] = dataclasses.replace(
+                self.moe, n_experts=n_exp, top_k=2, d_ff=64,
+                capacity_factor=2.0, n_group=4 if grouped else 1,
+                topk_group=2 if grouped else 1,
+                n_held=max(2, n_exp * self.moe.n_held // self.moe.n_experts)
+                if self.moe.n_held else 0,
+                held_first=n_exp * self.moe.held_first // self.moe.n_experts)
+            kw["first_k_dense"] = min(self.first_k_dense, 1)
         if self.mla is not None:
             kw["mla"] = MLAConfig(q_lora_rank=32, kv_lora_rank=16,
                                   rope_head_dim=8, nope_head_dim=16, v_head_dim=16)

@@ -20,13 +20,28 @@ _MODULES = {
 }
 
 
+# one chip's share of a stated deployment of an architecture above:
+# id -> (module, attribute). Not part of the zoo that ``arch_ids`` lists.
+_SHARES = {
+    "deepseek-v3-671b-ep32": ("repro.configs.deepseek_v3_671b", "EP32"),
+}
+
+
 def arch_ids() -> List[str]:
     return list(_MODULES)
 
 
+def share_ids() -> List[str]:
+    return list(_SHARES)
+
+
 def get_arch(name: str) -> ArchConfig:
+    if name in _SHARES:
+        module, attr = _SHARES[name]
+        return getattr(importlib.import_module(module), attr)
     if name not in _MODULES:
-        raise KeyError(f"unknown arch {name!r}; known: {sorted(_MODULES)}")
+        raise KeyError(f"unknown arch {name!r}; known: "
+                       f"{sorted(_MODULES) + sorted(_SHARES)}")
     return importlib.import_module(_MODULES[name]).CONFIG
 
 

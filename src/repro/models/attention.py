@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig, MLAConfig
 from repro.models.layers import (
     apply_rope, chunked_attention, decode_attention, dense, dt, init_dense,
-    rmsnorm, softmax_with_token,
+    rmsnorm, softmax_with_token, yarn_mscale,
 )
 
 # =========================================================== GQA attention
@@ -138,14 +138,31 @@ def mla_init(key, cfg: ArchConfig, dtype) -> dict:
     ks = jax.random.split(key, 6)
     return {
         "wq_a": init_dense(ks[0], d, m.q_lora_rank, dtype),
-        "q_norm": jnp.ones((m.q_lora_rank,), dtype=dtype),
+        "q_norm": {"scale": jnp.ones((m.q_lora_rank,), dtype=dtype)},
         "wq_b": init_dense(ks[1], m.q_lora_rank, H * qk, dtype),
         "wkv_a": init_dense(ks[2], d, m.kv_lora_rank + m.rope_head_dim, dtype),
-        "kv_norm": jnp.ones((m.kv_lora_rank,), dtype=dtype),
+        "kv_norm": {"scale": jnp.ones((m.kv_lora_rank,), dtype=dtype)},
         "wkv_b": init_dense(ks[3], m.kv_lora_rank,
                             H * (m.nope_head_dim + m.v_head_dim), dtype),
         "wo": init_dense(ks[4], H * m.v_head_dim, d, dtype),
     }
+
+
+def _mla_rope(x, positions, cfg):
+    """Rotary on interleaved pairs of the rope dims, as DeepSeek's code
+    applies it."""
+    return apply_rope(x, positions, cfg.rope_theta, cfg.rope_scaling,
+                      interleaved=True)
+
+
+def mla_scale(cfg: ArchConfig) -> float:
+    """The softmax scale: 1/sqrt(qk head size), times YaRN's mscale
+    squared where the rotary is YaRN-scaled."""
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
+    if cfg.rope_scaling is not None:
+        scale *= yarn_mscale(cfg.rope_scaling) ** 2
+    return scale
 
 
 def _mla_q(p, x, cfg, positions):
@@ -153,42 +170,47 @@ def _mla_q(p, x, cfg, positions):
     B, S, _ = x.shape
     H = cfg.n_heads
     cdt = dt(cfg.compute_dtype)
-    qa = rmsnorm(dense(p["wq_a"], x, cdt), p["q_norm"])
+    qa = rmsnorm(dense(p["wq_a"], x, cdt), p["q_norm"]["scale"], cfg.norm_eps)
     q = dense(p["wq_b"], qa, cdt).reshape(B, S, H, m.nope_head_dim + m.rope_head_dim)
     q_nope, q_rope = q[..., :m.nope_head_dim], q[..., m.nope_head_dim:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    return q_nope, q_rope
+    return q_nope, _mla_rope(q_rope, positions, cfg)
 
 
 def _mla_latent(p, x, cfg, positions):
     m = cfg.mla
     cdt = dt(cfg.compute_dtype)
     kv_a = dense(p["wkv_a"], x, cdt)                        # [B,S,lora+rope]
-    c_kv = rmsnorm(kv_a[..., :m.kv_lora_rank], p["kv_norm"])
-    k_rope = apply_rope(kv_a[..., None, m.kv_lora_rank:], positions,
-                        cfg.rope_theta)[..., 0, :]          # [B,S,rope] shared
+    c_kv = rmsnorm(kv_a[..., :m.kv_lora_rank], p["kv_norm"]["scale"],
+                   cfg.norm_eps)
+    k_rope = _mla_rope(kv_a[..., None, m.kv_lora_rank:], positions,
+                       cfg)[..., 0, :]                      # [B,S,rope] shared
     return c_kv, k_rope
 
 
-def mla_forward(p, x, cfg: ArchConfig, positions, causal=True):
+def _mla_attend(p, x, cfg, positions, c_kv, k_rope, causal=True):
+    """Decompressed attention of ``x``'s queries over the latents."""
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.n_heads
     cdt = dt(cfg.compute_dtype)
     q_nope, q_rope = _mla_q(p, x, cfg, positions)
-    c_kv, k_rope = _mla_latent(p, x, cfg, positions)
     kv = dense(p["wkv_b"], c_kv, cdt).reshape(B, S, H, m.nope_head_dim + m.v_head_dim)
     k_nope, v = kv[..., :m.nope_head_dim], kv[..., m.nope_head_dim:]
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, m.rope_head_dim))],
         axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
     o = chunked_attention(q, k, v, causal=causal,
                           chunk_q=cfg.attn_chunk_q, chunk_kv=cfg.attn_chunk_kv,
                           q_positions=positions, kv_positions=positions,
-                          scale=scale, compute_dtype=cdt)
+                          scale=mla_scale(cfg), compute_dtype=cdt)
     return dense(p["wo"], o.reshape(B, S, -1), cdt)
+
+
+def mla_forward(p, x, cfg: ArchConfig, positions, causal=True):
+    with jax.named_scope("mla"):
+        c_kv, k_rope = _mla_latent(p, x, cfg, positions)
+        return _mla_attend(p, x, cfg, positions, c_kv, k_rope, causal)
 
 
 def mla_init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype) -> dict:
@@ -198,13 +220,13 @@ def mla_init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype) -> dict:
 
 
 def mla_prefill(p, x, cfg: ArchConfig, cache, positions):
-    c_kv, k_rope = _mla_latent(p, x, cfg, positions)
-    cache = {"c_kv": jax.lax.dynamic_update_slice_in_dim(
-                 cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), 0, axis=1),
-             "k_rope": jax.lax.dynamic_update_slice_in_dim(
-                 cache["k_rope"], k_rope.astype(cache["k_rope"].dtype), 0, axis=1)}
-    y = mla_forward(p, x, cfg, positions)
-    return y, cache
+    with jax.named_scope("mla"):
+        c_kv, k_rope = _mla_latent(p, x, cfg, positions)
+        cache = {"c_kv": jax.lax.dynamic_update_slice_in_dim(
+                     cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), 0, axis=1),
+                 "k_rope": jax.lax.dynamic_update_slice_in_dim(
+                     cache["k_rope"], k_rope.astype(cache["k_rope"].dtype), 0, axis=1)}
+        return _mla_attend(p, x, cfg, positions, c_kv, k_rope), cache
 
 
 def _mla_wkv_b_split(p, cfg):
@@ -219,6 +241,11 @@ def mla_decode_token(p, x, cfg: ArchConfig, cache, lengths):
     over the cache's first ``lengths[b]`` positions and the token itself.
     Returns ``(y, new)`` as ``gqa_decode_token`` does, ``new`` the token's
     ``{"c_kv": [B,lora], "k_rope": [B,rope]}``."""
+    with jax.named_scope("mla"):
+        return _mla_decode_token(p, x, cfg, cache, lengths)
+
+
+def _mla_decode_token(p, x, cfg: ArchConfig, cache, lengths):
     m = cfg.mla
     B = x.shape[0]
     H = cfg.n_heads
@@ -239,7 +266,7 @@ def mla_decode_token(p, x, cfg: ArchConfig, cache, lengths):
                            preferred_element_type=jnp.float32)
                 + jnp.einsum("bshr,btr->bhst", q_rope.astype(cdt),
                              r.astype(cdt), preferred_element_type=jnp.float32)
-                ) / math.sqrt(m.nope_head_dim + m.rope_head_dim)
+                ) * mla_scale(cfg)
 
     def readout(pattn, c):                                  # -> [B,1,H,lora]
         return jnp.einsum("bhst,btl->bshl", pattn.astype(cdt), c.astype(cdt),
@@ -277,7 +304,7 @@ def mla_decode_naive(p, x, cfg: ArchConfig, cache, lengths):
         [k_nope, jnp.broadcast_to(krp[:, :, None, :].astype(cdt), (B, Smax, H, m.rope_head_dim))],
         axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
+    scale = mla_scale(cfg)
     o = decode_attention(q, k, v, lengths + 1, scale=scale, compute_dtype=cdt)
     y = dense(p["wo"], o.reshape(B, 1, -1), cdt)
     return y, {"c_kv": ckv, "k_rope": krp}

@@ -53,20 +53,55 @@ def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarra
 
 # ------------------------------------------------------------------ RoPE
 
-def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_range(head_dim: int, theta: float, yarn) -> tuple:
+    """YaRN's correction range: the first and last rotary pair whose
+    frequency is ramped between extrapolation and interpolation."""
+    def dim(rotations):
+        return head_dim * math.log(yarn.original_max_position
+                                   / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    return (max(math.floor(dim(yarn.beta_fast)), 0),
+            min(math.ceil(dim(yarn.beta_slow)), head_dim - 1))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+def yarn_mscale(yarn) -> float:
+    """YaRN's attention temperature factor; the softmax scale is
+    multiplied by its square."""
+    return 0.1 * yarn.mscale_all_dim * math.log(yarn.factor) + 1.0
+
+
+def rope_freqs(head_dim: int, theta: float, yarn=None) -> jnp.ndarray:
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if yarn is None:
+        return freqs
+    if yarn.mscale != yarn.mscale_all_dim:
+        raise ValueError("YaRN with mscale != mscale_all_dim scales cos and "
+                         "sin, which is not implemented")
+    low, high = yarn_range(head_dim, theta, yarn)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               yarn=None, interleaved: bool = False) -> jnp.ndarray:
     """x: [..., S, ..., D] with positions broadcastable to x's S dim.
 
-    x layout: [B, S, H, D]; positions: [B, S] or [S].
+    x layout: [B, S, H, D]; positions: [B, S] or [S]. Rotates the two
+    halves of D, or with ``interleaved`` the pairs (0, 1), (2, 3), ...;
+    ``yarn`` (a ``YarnConfig``) scales the frequencies.
     """
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                     # [D/2]
+    freqs = rope_freqs(d, theta, yarn)               # [D/2]
     ang = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
     cos = jnp.cos(ang)[..., None, :]                  # [B, S, 1, D/2]
     sin = jnp.sin(ang)[..., None, :]
+    if interleaved:
+        xp = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
+        x1, x2 = xp[..., 0], xp[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                        axis=-1).reshape(x.shape)
+        return out.astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -1,10 +1,14 @@
 """Mixture-of-Experts FFN with expert parallelism.
 
-Three dispatch strategies, all numerically equivalent up to capacity
-drops (tested against each other):
+Three dispatch strategies:
 
-  * ``local``      — no mesh (smoke tests): capacity-bucketed batched
-                     matmul on one device.
+  * ``held``       — no mesh: the experts this device holds (all of them,
+                     or the block ``MoEConfig.n_held`` names) compute
+                     every (token, held expert) pair, dropless, as a
+                     grouped matmul over the pairs sorted by expert. The
+                     router scores all experts; pairs routed to experts
+                     held elsewhere add nothing here. Oracle for the
+                     sharded paths.
   * ``a2a``        — shard_map expert parallelism: tokens split over the
                      model axis, bucketed per destination expert shard,
                      exchanged with ``lax.all_to_all``, expert-batched
@@ -15,16 +19,26 @@ drops (tested against each other):
                      computes only its own experts; partial outputs are
                      psum'd. No a2a; right for tiny decode batches.
 
+The sharded paths drop tokens beyond their capacity buckets
+(``capacity_factor``); with room enough they equal ``held``.
+
 Expert-count < model-axis handling (grok: 8 experts on 16 shards): the
 expert hidden dim is split tp_e = M/E ways and each token is dispatched to
 all tp_e shards of its expert group; the partial FFN outputs simply add in
-the source-side combine (no extra collective). Weight layout is therefore
-device-major: ``[M, Epg, d, ffl]`` — see ``expert_layout``.
+the source-side combine (no extra collective). Expert matrices put their
+input width first and the device-major experts side by side in the
+second: ``[d, M*Epg*ffl]`` for gate and up, ``[ffl, M*Epg*d]`` for down,
+expert slot ``m*Epg + j`` (device m's j-th expert, or its share of an
+expert's hidden dim) in columns ``slot*ffl ..`` resp. ``slot*d ..`` — see
+``expert_layout``. Without a mesh that is ``[d, E_held*d_ff]``. An
+expert is then a tile-aligned block of columns, which a device reads
+without reading its neighbours.
 """
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +60,9 @@ class ExpertLayout:
 def expert_layout(cfg: ArchConfig, model_size: int) -> ExpertLayout:
     E = cfg.moe.n_experts
     M = max(model_size, 1)
+    if cfg.moe.n_held and M > 1:
+        raise ValueError("a held block of experts is one device's share; "
+                         "it is not laid out over a mesh")
     ep = math.gcd(E, M)
     tp_e = M // ep
     if E % ep or M % ep:
@@ -53,14 +70,17 @@ def expert_layout(cfg: ArchConfig, model_size: int) -> ExpertLayout:
     ffe = cfg.moe.d_ff or cfg.d_ff
     if ffe % tp_e:
         raise ValueError(f"expert d_ff {ffe} not divisible by tp_e {tp_e}")
-    return ExpertLayout(M=M, ep=ep, tp_e=tp_e, epg=E // ep, ffl=ffe // tp_e)
+    epg = cfg.moe.n_held or E // ep
+    return ExpertLayout(M=M, ep=ep, tp_e=tp_e, epg=epg, ffl=ffe // tp_e)
 
 
 def moe_init(key, cfg: ArchConfig, dtype, model_size: int) -> dict:
-    """Device-major expert weights: [M, Epg, d, ffl] / [M, Epg, ffl, d]."""
+    """Expert weights ``[d, M*Epg*ffl]`` / ``[ffl, M*Epg*d]``; the router
+    over all experts, and with sigmoid scoring its correction ``bias``."""
     lay = expert_layout(cfg, model_size)
     d = cfg.d_model
     E = cfg.moe.n_experts
+    slots = lay.M * lay.epg
     ks = jax.random.split(key, 6)
     std = 1.0 / math.sqrt(d)
     std_ff = 1.0 / math.sqrt(lay.ffl * lay.tp_e)
@@ -70,11 +90,13 @@ def moe_init(key, cfg: ArchConfig, dtype, model_size: int) -> dict:
 
     p = {
         "router": w(ks[0], (d, E), std),
-        "up": w(ks[1], (lay.M, lay.epg, d, lay.ffl), std),
-        "down": w(ks[2], (lay.M, lay.epg, lay.ffl, d), std_ff),
+        "up": w(ks[1], (d, slots * lay.ffl), std),
+        "down": w(ks[2], (lay.ffl, slots * d), std_ff),
     }
+    if cfg.moe.scoring == "sigmoid":
+        p["bias"] = jnp.zeros((E,), dtype)
     if cfg.glu:
-        p["gate"] = w(ks[3], (lay.M, lay.epg, d, lay.ffl), std)
+        p["gate"] = w(ks[3], (d, slots * lay.ffl), std)
     if cfg.moe.n_shared:
         ffe = (cfg.moe.d_ff or cfg.d_ff) * cfg.moe.n_shared
         p["shared"] = {
@@ -99,13 +121,17 @@ def moe_param_specs(cfg: ArchConfig, dist) -> dict:
         fs = None          # experts fully sharded by EP itself
     else:
         fs = dist.dp_axes if (dist.fsdp and dist.dp_axes) else None
+    # down's d lies inside its expert columns: split them over ep, then fs
+    down = (*(ep or ()), *(fs or ()))
     specs = {
         "router": P(None, None),
-        "up": P(ep, None, fs, None),
-        "down": P(ep, None, None, fs),
+        "up": P(fs, ep),
+        "down": P(None, down or None),
     }
+    if cfg.moe.scoring == "sigmoid":
+        specs["bias"] = P(None)
     if cfg.glu:
-        specs["gate"] = P(ep, None, fs, None)
+        specs["gate"] = P(fs, ep)
     if cfg.moe.n_shared:
         specs["shared"] = {"up": P(None, None), "down": P(None, None)}
         if cfg.glu:
@@ -114,31 +140,41 @@ def moe_param_specs(cfg: ArchConfig, dist) -> dict:
 
 
 def _gather_experts(p, dist):
-    """Inside shard_map: reconstruct full [Epg, d, ffl] expert blocks by
-    all-gathering the FSDP-sharded dim over the dp axes. With ep_over_dp
-    the weights are already fully local (no FSDP dim)."""
+    """Inside shard_map: reconstruct this device's whole ``[d, Epg*ffl]``
+    / ``[ffl, Epg*d]`` expert blocks by all-gathering what FSDP split over
+    the dp axes. With ep_over_dp the weights are already fully local (no
+    FSDP dim)."""
     if dist.ep_over_dp or not (dist.fsdp and dist.dp_axes):
-        return {k: (p[k][0] if k in ("up", "down", "gate") else p[k])
-                for k in p}
+        return p
     ax = dist.dp_axes if len(dist.dp_axes) > 1 else dist.dp_axes[0]
     out = dict(p)
-    out["up"] = jax.lax.all_gather(p["up"][0], ax, axis=1, tiled=True)
+    out["up"] = jax.lax.all_gather(p["up"], ax, axis=0, tiled=True)
     if "gate" in p:
-        out["gate"] = jax.lax.all_gather(p["gate"][0], ax, axis=1, tiled=True)
-    out["down"] = jax.lax.all_gather(p["down"][0], ax, axis=2, tiled=True)
+        out["gate"] = jax.lax.all_gather(p["gate"], ax, axis=0, tiled=True)
+    out["down"] = jax.lax.all_gather(p["down"], ax, axis=1, tiled=True)
     return out
 
 
 # ------------------------------------------------------------ primitives
 
 
-def _route(x, router_w, cfg: ArchConfig):
-    """Returns (weights [T,k] f32, ids [T,k] i32, aux dict)."""
+def _route(x, p, cfg: ArchConfig):
+    """Returns (weights [T,k] f32, ids [T,k] i32, aux dict), choosing among
+    all ``n_experts`` as ``MoEConfig`` describes."""
     moe = cfg.moe
-    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, ids = jax.lax.top_k(probs, moe.top_k)
+    logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32))
+    if moe.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        probs = scores / scores.sum(-1, keepdims=True)
+        ids = _group_limited_top_k(scores + p["bias"].astype(jnp.float32),
+                                   moe)
+        w = jnp.take_along_axis(scores, ids, -1)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        w, ids = jax.lax.top_k(probs, moe.top_k)
     w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    if moe.routed_scaling != 1.0:
+        w = w * moe.routed_scaling
     # load-balance loss (Switch-style) + router z-loss, local means
     me = probs.mean(0)                                     # [E]
     ce = jnp.zeros((moe.n_experts,), jnp.float32).at[ids.reshape(-1)].add(
@@ -148,18 +184,39 @@ def _route(x, router_w, cfg: ArchConfig):
     return w, ids, {"lb_loss": lb, "z_loss": z}
 
 
+def _group_limited_top_k(s, moe):
+    """Top-k expert ids of the choice scores ``s [T, E]``, taken from the
+    ``topk_group`` groups whose two best scores sum highest."""
+    T, E = s.shape
+    if moe.n_group > 1:
+        g = s.reshape(T, moe.n_group, E // moe.n_group)
+        group_score = jax.lax.top_k(g, 2)[0].sum(-1)            # [T, G]
+        keep = jax.lax.top_k(group_score, moe.topk_group)[1]
+        kept = jax.nn.one_hot(keep, moe.n_group, dtype=jnp.int32).sum(1)
+        s = jnp.where(kept[:, :, None] > 0, g, -jnp.inf).reshape(T, E)
+    return jax.lax.top_k(s, moe.top_k)[1]
+
+
 def _expert_ffn(hbuf, p_gate, p_up, p_down, act: str, glu: bool, cdt):
-    """hbuf [E?, C, d] x per-expert weights [E?, d, ffl] -> [E?, C, d]."""
-    h = jnp.einsum("ecd,edf->ecf", hbuf.astype(cdt), p_up.astype(cdt))
+    """hbuf [E?, C, d] x per-expert weights [d, E?*ffl] / [ffl, E?*d]
+    -> [E?, C, d]."""
+    E, _, d = hbuf.shape
+
+    def w(m, per):      # [d_in, E*per] -> [d_in, E, per]
+        return m.reshape(m.shape[0], E, per).astype(cdt)
+
+    ffl = p_up.shape[1] // E
+    h = jnp.einsum("ecd,def->ecf", hbuf.astype(cdt), w(p_up, ffl))
     if glu:
-        g = jnp.einsum("ecd,edf->ecf", hbuf.astype(cdt), p_gate.astype(cdt))
+        g = jnp.einsum("ecd,def->ecf", hbuf.astype(cdt), w(p_gate, ffl))
         h = ACTS[act](g) * h
     else:
         h = ACTS[act](h)
-    return jnp.einsum("ecf,efd->ecd", h, p_down.astype(cdt))
+    return jnp.einsum("ecf,fed->ecd", h, w(p_down, d))
 
 
-def _shared_ffn(x, p, cfg, cdt):
+def _ffn(x, p, cfg, cdt):
+    """One expert (``p``: ``up``, ``down``, ``gate`` matrices) on ``x``."""
     h = jnp.dot(x.astype(cdt), p["up"].astype(cdt))
     if cfg.glu:
         h = ACTS[cfg.act](jnp.dot(x.astype(cdt), p["gate"].astype(cdt))) * h
@@ -168,37 +225,104 @@ def _shared_ffn(x, p, cfg, cdt):
     return jnp.dot(h, p["down"].astype(cdt))
 
 
-# -------------------------------------------------------- local dispatch
+# -------------------------------------------------------- held experts
+
+EXPERT_MATRICES = ("gate", "up", "down")
 
 
-def moe_local(p, x2, cfg: ArchConfig):
-    """Single-device capacity-bucketed MoE; oracle for the sharded paths."""
-    lay = expert_layout(cfg, 1)
+class Stacked(NamedTuple):
+    """A layer's expert matrix inside the whole stack of layers: ``a`` is
+    ``[L, ...]`` and the layer is ``a[i]``. The held-expert layer slices
+    one expert out of it only where that expert has rows, so that a layer
+    loop need not slice (and copy) every layer's experts whole."""
+    a: jax.Array
+    i: jax.Array
+
+
+def split_experts(p: dict) -> tuple[dict, dict]:
+    """A stacked MoE block's parameters as ``(rest, whole)``: ``whole``
+    holds the expert matrices, left in the stack of layers for
+    ``at_layer`` to index; ``rest`` is sliced per layer as usual."""
+    whole = {n: w for n, w in p.items() if n in EXPERT_MATRICES}
+    return {n: w for n, w in p.items() if n not in whole}, whole
+
+
+def at_layer(rest_l: dict, whole: dict, i) -> dict:
+    """Layer ``i``'s MoE block parameters: its slice ``rest_l`` of
+    ``split_experts``' ``rest``, and its expert matrices as ``Stacked``
+    views of ``whole``."""
+    return {**rest_l, **{n: Stacked(w, i) for n, w in whole.items()}}
+
+
+def _expert(w, e, width: int):
+    """Expert ``e``'s ``[d_in, width]`` block of ``w``, ``[d_in,
+    E*width]`` or a ``Stacked`` of such."""
+    if isinstance(w, Stacked):
+        d_in = w.a.shape[1]
+        return jax.lax.dynamic_slice(w.a, (w.i, 0, e * width),
+                                     (1, d_in, width))[0]
+    return jax.lax.dynamic_slice_in_dim(w, e * width, width, axis=1)
+
+
+# rows of a tile of the grouped matmul: large enough to keep the MXU busy
+# at prefill, where an expert held here sees a few hundred tokens
+TILE_ROWS = 256
+
+
+def _held_experts(p, x2, w, ids, cfg: ArchConfig, cdt):
+    """This device's experts' part of the layer, dropless: every (token,
+    held expert) pair, weighted by its routing weight, summed per token.
+    The pairs are sorted by expert and each expert runs over its own rows
+    in tiles; a tile with no row of its expert is skipped, so an expert
+    that no token chose is not read."""
     moe = cfg.moe
-    cdt = dt(cfg.compute_dtype)
     T, d = x2.shape
-    w, ids, aux = _route(x2, p["router"], cfg)
-    E = moe.n_experts
-    C = max(1, int(math.ceil(T * moe.top_k / E * moe.capacity_factor)))
-    f_ids = ids.reshape(-1)                                 # [T*k]
-    f_w = w.reshape(-1)
-    f_tok = jnp.repeat(jnp.arange(T), moe.top_k)
-    oh = jax.nn.one_hot(f_ids, E, dtype=jnp.int32)
-    pos = (jnp.cumsum(oh, axis=0) - 1)
-    pos = jnp.take_along_axis(pos, f_ids[:, None], axis=1)[:, 0]
-    valid = pos < C
-    aux["drop_frac"] = 1.0 - valid.mean()
-    buf = jnp.zeros((E, C, d), x2.dtype).at[f_ids, jnp.where(valid, pos, C)].set(
-        x2[f_tok], mode="drop")
-    # weights are stored device-major [M=1, Epg=E, ...]
-    gate = p["gate"][0] if cfg.glu else None
-    out_buf = _expert_ffn(buf, gate, p["up"][0], p["down"][0],
-                          cfg.act, cfg.glu, cdt)
-    rows = out_buf[f_ids, jnp.clip(pos, 0, C - 1)]          # [T*k, d]
-    rows = rows * (valid[:, None] & True) * f_w[:, None]
-    y = jnp.zeros((T, d), jnp.float32).at[f_tok].add(rows.astype(jnp.float32))
-    if moe.n_shared:
-        y = y + _shared_ffn(x2, p["shared"], cfg, cdt).astype(jnp.float32)
+    k = ids.shape[1]
+    lay = expert_layout(cfg, 1)
+    E, width = lay.epg, {"gate": lay.ffl, "up": lay.ffl, "down": d}
+    R = min(TILE_ROWS, T)            # an expert has at most T rows
+    n_tiles = -(-T // R)
+    local = ids.reshape(-1) - moe.held_first
+    g = jnp.where((local >= 0) & (local < E), local, E)    # E: held elsewhere
+    order = jnp.argsort(g, stable=True)
+    sizes = jnp.sum(g[:, None] == jnp.arange(E)[None, :], axis=0)
+    starts = jnp.cumsum(sizes) - sizes
+    tok = jnp.concatenate([order // k, jnp.zeros((R,), order.dtype)])
+    wt = jnp.concatenate([w.reshape(-1)[order], jnp.zeros((R,), w.dtype)])
+    names = [n for n in EXPERT_MATRICES if n in p]
+
+    def tile(y, j):
+        e, i = j // n_tiles, j % n_tiles
+
+        def run(y):
+            at = starts[e] + i * R
+            rows = jax.lax.dynamic_slice_in_dim(tok, at, R)
+            rw = jnp.where(jnp.arange(R) < sizes[e] - i * R,
+                           jax.lax.dynamic_slice_in_dim(wt, at, R), 0.0)
+            ex = {n: _expert(p[n], e, width[n]) for n in names}
+            out = _ffn(x2[rows], ex, cfg, cdt).astype(jnp.float32)
+            return y.at[rows].add(out * rw[:, None])
+
+        return jax.lax.cond(i * R < sizes[e], run, lambda y: y, y), None
+
+    y, _ = jax.lax.scan(tile, jnp.zeros((T, d), jnp.float32),
+                        jnp.arange(E * n_tiles))
+    return y
+
+
+def moe_held(p, x2, cfg: ArchConfig):
+    """The layer on one device without a mesh: route over all experts,
+    compute the held experts' pairs (``_held_experts``) and the shared
+    expert."""
+    cdt = dt(cfg.compute_dtype)
+    with jax.named_scope("moe.route"):
+        w, ids, aux = _route(x2, p, cfg)
+    with jax.named_scope("moe.experts"):
+        y = _held_experts(p, x2, w, ids, cfg, cdt)
+    aux["drop_frac"] = jnp.zeros((), jnp.float32)
+    if cfg.moe.n_shared:
+        with jax.named_scope("moe.shared"):
+            y = y + _ffn(x2, p["shared"], cfg, cdt).astype(jnp.float32)
     return y.astype(x2.dtype), aux
 
 
@@ -212,7 +336,7 @@ def _moe_replicated_body(p, x2, cfg: ArchConfig, lay: ExpertLayout, dist):
     cdt = dt(cfg.compute_dtype)
     T, d = x2.shape
     pe = _gather_experts(p, dist)
-    w, ids, aux = _route(x2, p["router"], cfg)
+    w, ids, aux = _route(x2, p, cfg)
     midx = jax.lax.axis_index(model_axis) if model_axis else 0
     ep_rank = midx // lay.tp_e
     # global expert id range owned by this shard: [ep_rank*epg, ...)
@@ -238,7 +362,7 @@ def _moe_replicated_body(p, x2, cfg: ArchConfig, lay: ExpertLayout, dist):
     if model_axis is not None:
         y = jax.lax.psum(y, model_axis)
     if moe.n_shared:
-        y = y + _shared_ffn(x2, p["shared"], cfg, cdt).astype(jnp.float32)
+        y = y + _ffn(x2, p["shared"], cfg, cdt).astype(jnp.float32)
     aux["drop_frac"] = 1.0 - (valid.sum() / jnp.maximum(local.sum(), 1))
     return y.astype(x2.dtype), aux
 
@@ -262,7 +386,7 @@ def _moe_a2a_body(p, x2, cfg: ArchConfig, lay: ExpertLayout, dist):
     M_split = jax.lax.psum(1, model_axis)
     Tm = T // dist.model_size
     x_my = jax.lax.dynamic_slice_in_dim(x2, midx * Tm, Tm)  # [Tm, d]
-    w, ids, aux = _route(x_my, p["router"], cfg)
+    w, ids, aux = _route(x_my, p, cfg)
 
     # flat entries: token x top-k x tp_e destinations
     f_ids = jnp.repeat(ids.reshape(-1), tpe)                # [Tm*k*tpe]
@@ -307,7 +431,7 @@ def _moe_a2a_body(p, x2, cfg: ArchConfig, lay: ExpertLayout, dist):
     got = jnp.where(valid[:, None], got, 0) * f_w[:, None].astype(got.dtype)
     y_my = jnp.zeros((Tm, d), jnp.float32).at[f_tok].add(got.astype(jnp.float32))
     if moe.n_shared:
-        y_my = y_my + _shared_ffn(x_my, p["shared"], cfg, cdt).astype(jnp.float32)
+        y_my = y_my + _ffn(x_my, p["shared"], cfg, cdt).astype(jnp.float32)
     y = jax.lax.all_gather(y_my.astype(x2.dtype), model_axis, axis=0,
                            tiled=True)                      # [T, d]
     return y, aux
@@ -323,7 +447,7 @@ def moe_block(p, x, cfg: ArchConfig, dist, dispatch: str = "auto"):
     if not dist.active or dist.model_size == 1:
         if dist.active:
             x2 = dist.constrain(x2, P(dist.dp_axes, None))
-        y, aux = moe_local(p, x2, cfg)
+        y, aux = moe_held(p, x2, cfg)
         return y.reshape(B, S, d), aux
 
     lay = expert_layout(cfg, dist.ep_size)

@@ -5,8 +5,11 @@ starcoder2-15b, deepseek-v3-671b, grok-1-314b (and the VLM/early-fusion
 case, whose frontend is a token stream).
 
 Parameters are stacked along a leading layer axis and consumed with
-``jax.lax.scan``; remat policy is applied per layer. The cross-entropy is
-computed in sequence chunks under ``jax.checkpoint`` so full-vocab logits
+``jax.lax.scan``; remat policy is applied per layer. An MoE model's
+leading dense layers (``first_k_dense``) are a list of per-layer trees,
+``params["dense_layers"]``, run ahead of the scan over the stacked MoE
+``params["layers"]``; the KV cache stacks all layers, dense ones first.
+The cross-entropy is computed in sequence chunks under ``jax.checkpoint`` so full-vocab logits
 never materialize ([B,S,V] at 129k vocab would dominate memory).
 """
 from __future__ import annotations
@@ -21,7 +24,9 @@ from repro.models import attention as attn
 from repro.models.layers import (
     apply_norm, dt, init_embedding, init_mlp, init_norm, mlp, unembed,
 )
-from repro.models.moe import moe_block, moe_init, moe_param_specs
+from repro.models.moe import (
+    at_layer, moe_block, moe_init, moe_param_specs, split_experts,
+)
 from repro.dist.context import DistContext, no_dist
 
 REMAT_POLICIES = {
@@ -34,7 +39,8 @@ REMAT_POLICIES = {
 # ------------------------------------------------------------------ init
 
 
-def _layer_init(key, cfg: ArchConfig, dtype, model_size: int) -> dict:
+def _layer_init(key, cfg: ArchConfig, dtype, model_size: int,
+                moe: bool = True) -> dict:
     ks = jax.random.split(key, 4)
     if cfg.attention == "mla":
         a = attn.mla_init(ks[0], cfg, dtype)
@@ -43,7 +49,7 @@ def _layer_init(key, cfg: ArchConfig, dtype, model_size: int) -> dict:
     p = {"attn": a,
          "norm1": init_norm(cfg.d_model, cfg.norm, dtype),
          "norm2": init_norm(cfg.d_model, cfg.norm, dtype)}
-    if cfg.moe is not None:
+    if cfg.moe is not None and moe:
         p["moe"] = moe_init(ks[1], cfg, dtype, model_size)
     else:
         p["mlp"] = init_mlp(ks[1], cfg.d_model, cfg.d_ff, cfg.glu, dtype)
@@ -53,12 +59,16 @@ def _layer_init(key, cfg: ArchConfig, dtype, model_size: int) -> dict:
 def lm_init(key, cfg: ArchConfig, dist: DistContext = no_dist()) -> dict:
     dtype = dt(cfg.param_dtype)
     ks = jax.random.split(key, 3)
+    K = cfg.first_k_dense
     layer_keys = jax.random.split(ks[0], cfg.n_layers)
     layers = jax.vmap(lambda k: _layer_init(k, cfg, dtype, dist.ep_size))(
-        layer_keys)
+        layer_keys[K:])
     p = {"embed": init_embedding(ks[1], cfg.vocab, cfg.d_model, dtype),
          "layers": layers,
          "final_norm": init_norm(cfg.d_model, cfg.norm, dtype)}
+    if K:
+        p["dense_layers"] = [_layer_init(k, cfg, dtype, dist.ep_size,
+                                         moe=False) for k in layer_keys[:K]]
     if not cfg.tie_embeddings:
         p["unembed"] = init_embedding(ks[2], cfg.vocab, cfg.d_model, dtype)
     return p
@@ -92,7 +102,8 @@ def lm_param_specs(cfg: ArchConfig, dist: DistContext) -> dict:
              "wkv_a": stack(P(fs, None)), "kv_norm": stack(P(None)),
              "wkv_b": stack(P(None, m)),
              "wo": stack(P(m, fs))}
-        a = {k: ({"w": v} if k.startswith("w") else v) for k, v in a.items()}
+        a = {k: ({"w": v} if k.startswith("w") else {"scale": v})
+             for k, v in a.items()}
     else:
         a = {"wq": {"w": stack(P(fs, m))},
              "wk": {"w": stack(P(fs, m))},
@@ -107,20 +118,27 @@ def lm_param_specs(cfg: ArchConfig, dist: DistContext) -> dict:
     specs = {"attn": a,
              "norm1": _norm_spec(cfg, stack),
              "norm2": _norm_spec(cfg, stack)}
+    mp = {"up": {"w": stack(P(fs, m))}, "down": {"w": stack(P(m, fs))}}
+    if cfg.glu:
+        mp["gate"] = {"w": stack(P(fs, m))}
     if cfg.moe is not None:
         ms = moe_param_specs(cfg, dist)
         specs["moe"] = jax.tree_util.tree_map(
             lambda s: P(L, *s), ms, is_leaf=lambda s: isinstance(s, P))
     else:
-        mp = {"up": {"w": stack(P(fs, m))}, "down": {"w": stack(P(m, fs))}}
-        if cfg.glu:
-            mp["gate"] = {"w": stack(P(fs, m))}
         specs["mlp"] = mp
     out = {"embed": P(m, fs),
            "layers": specs,
            "final_norm": _norm_spec(cfg, lambda s: s)}
     if not cfg.tie_embeddings:
         out["unembed"] = P(m, fs)
+    if cfg.first_k_dense:
+        # an unstacked layer: the stacked specs without their layer axis
+        dense = {"attn": a, "norm1": specs["norm1"],
+                 "norm2": specs["norm2"], "mlp": mp}
+        dense = jax.tree_util.tree_map(lambda s: P(*s[1:]), dense,
+                                       is_leaf=lambda s: isinstance(s, P))
+        out["dense_layers"] = [dense] * cfg.first_k_dense
     return out
 
 
@@ -138,20 +156,44 @@ def lm_init_abstract(cfg: ArchConfig, dist: DistContext):
 # --------------------------------------------------------------- forward
 
 
+def _serve_scan(f, carry, layers, xs, dist: DistContext):
+    """``lax.scan`` of ``f(carry, (p_l, xs_l))`` over the stacked layers,
+    for the serving steps. On one device an MoE layer's expert matrices
+    are not sliced out of the stack per layer, which would copy them every
+    call: the block reads them in the whole stack (``moe.at_layer``), and
+    an expert that no token chose is not read."""
+    if dist.active or "moe" not in layers:
+        return jax.lax.scan(f, carry, (layers, xs))
+    rest, whole = split_experts(layers["moe"])
+
+    def step(c, sl):
+        (p_l, xs_l), i = sl
+        return f(c, ({**p_l, "moe": at_layer(p_l["moe"], whole, i)}, xs_l))
+
+    n_layers = jax.tree.leaves(xs)[0].shape[0]
+    return jax.lax.scan(step, carry, (({**layers, "moe": rest}, xs),
+                                      jnp.arange(n_layers)))
+
+
+def _ffn(p, h, cfg: ArchConfig, dist: DistContext, **moe_kw):
+    """A layer's MoE block or dense MLP: ``(y, aux)``, aux None if dense."""
+    if "moe" in p:
+        return moe_block(p["moe"], h, cfg, dist, **moe_kw)
+    with jax.named_scope("mlp.dense"):
+        return mlp(p["mlp"], h, cfg.act, cfg.glu, dt(cfg.compute_dtype)), None
+
+
 def _layer_fwd(p, x, positions, cfg: ArchConfig, dist: DistContext):
     sp = dist.model_axis if (dist.active and dist.seq_parallel) else None
     xs = P(dist.dp_axes, sp, None) if dist.active else None
-    h = apply_norm(p["norm1"], x, cfg.norm)
+    h = apply_norm(p["norm1"], x, cfg.norm, cfg.norm_eps)
     if cfg.attention == "mla":
         y = attn.mla_forward(p["attn"], h, cfg, positions)
     else:
         y = attn.gqa_forward(p["attn"], h, cfg, positions)
     x = dist.constrain(x + y, xs) if dist.active else x + y
-    h = apply_norm(p["norm2"], x, cfg.norm)
-    if cfg.moe is not None:
-        y, aux = moe_block(p["moe"], h, cfg, dist)
-    else:
-        y, aux = mlp(p["mlp"], h, cfg.act, cfg.glu, dt(cfg.compute_dtype)), None
+    h = apply_norm(p["norm2"], x, cfg.norm, cfg.norm_eps)
+    y, aux = _ffn(p, h, cfg, dist)
     x = dist.constrain(x + y, xs) if dist.active else x + y
     return x, aux
 
@@ -185,8 +227,11 @@ def lm_backbone(params, tokens, cfg: ArchConfig, dist: DistContext,
     pol = REMAT_POLICIES.get(remat)
     if remat != "none":
         f = jax.checkpoint(body, policy=pol)
-    (x, aux), _ = jax.lax.scan(f, (x, _zero_aux()), params["layers"])
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+    carry = (x, _zero_aux())
+    for p_l in params.get("dense_layers", ()):
+        carry, _ = f(carry, p_l)
+    (x, aux), _ = jax.lax.scan(f, carry, params["layers"])
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     return x, aux
 
 
@@ -272,22 +317,28 @@ def lm_prefill(params, tokens, cfg: ArchConfig, cache,
     def body(carry, sl):
         x, = carry
         p_l, cache_l = sl
-        h = apply_norm(p_l["norm1"], x, cfg.norm)
+        h = apply_norm(p_l["norm1"], x, cfg.norm, cfg.norm_eps)
         if cfg.attention == "mla":
             y, cache_l = attn.mla_prefill(p_l["attn"], h, cfg, cache_l, positions)
         else:
             y, cache_l = attn.gqa_prefill(p_l["attn"], h, cfg, cache_l, positions)
         x = x + y
-        h = apply_norm(p_l["norm2"], x, cfg.norm)
-        if cfg.moe is not None:
-            y, _ = moe_block(p_l["moe"], h, cfg, dist)
-        else:
-            y = mlp(p_l["mlp"], h, cfg.act, cfg.glu, cdt)
+        h = apply_norm(p_l["norm2"], x, cfg.norm, cfg.norm_eps)
+        y, _ = _ffn(p_l, h, cfg, dist)
         return (x + y,), cache_l
 
     f = jax.checkpoint(body, policy=None) if remat != "none" else body
-    (x,), new_cache = jax.lax.scan(f, (x,), (params["layers"], cache))
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+    K = cfg.first_k_dense
+    dense = []
+    for i, p_l in enumerate(params.get("dense_layers", ())):
+        (x,), c = f((x,), (p_l, {k: v[i] for k, v in cache.items()}))
+        dense.append(c)
+    (x,), new_cache = _serve_scan(f, (x,), params["layers"],
+                                  {k: v[K:] for k, v in cache.items()}, dist)
+    if dense:
+        new_cache = {k: jnp.concatenate([jnp.stack([c[k] for c in dense]),
+                                         v]) for k, v in new_cache.items()}
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     w = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = unembed(x[:, -1:, :], w, cdt)
     return logits[:, 0, :], new_cache
@@ -308,20 +359,26 @@ def lm_decode_step(params, cache, tokens, lengths, cfg: ArchConfig,
 
     def body(x, sl):
         p_l, layer = sl
-        h = apply_norm(p_l["norm1"], x, cfg.norm)
+        h = apply_norm(p_l["norm1"], x, cfg.norm, cfg.norm_eps)
         cache_l = {k: c[layer] for k, c in cache.items()}
         y, new = decode(p_l["attn"], h, cfg, cache_l, lengths)
         x = x + y
-        h = apply_norm(p_l["norm2"], x, cfg.norm)
-        if cfg.moe is not None:
-            y, _ = moe_block(p_l["moe"], h, cfg, dist, dispatch="replicated" if dist.active else "auto")
-        else:
-            y = mlp(p_l["mlp"], h, cfg.act, cfg.glu, cdt)
+        h = apply_norm(p_l["norm2"], x, cfg.norm, cfg.norm_eps)
+        y, _ = _ffn(p_l, h, cfg, dist,
+                    dispatch="replicated" if dist.active else "auto")
         return x + y, new
 
-    x, new = jax.lax.scan(body, x, (params["layers"],
-                                    jnp.arange(cfg.n_layers)))
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+    K = cfg.first_k_dense
+    dense = []
+    for i, p_l in enumerate(params.get("dense_layers", ())):
+        x, n = body(x, (p_l, i))
+        dense.append(n)
+    x, new = _serve_scan(body, x, params["layers"],
+                         jnp.arange(K, cfg.n_layers), dist)
+    if dense:
+        new = {k: jnp.concatenate([jnp.stack([n[k] for n in dense]), v])
+               for k, v in new.items()}
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     w = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = unembed(x, w, cdt)
     return logits[:, 0, :], attn.write_tokens(cache, new, lengths,

@@ -6,11 +6,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import arch_ids, get_arch
+from repro.configs import arch_ids, get_arch, share_ids
 from repro.dist.context import no_dist
 from repro.models.api import build_model
 
-ARCHS = arch_ids()
+ARCHS = arch_ids() + share_ids()
 
 
 def _batch(cfg, B, S, key):
@@ -56,8 +56,8 @@ def test_smoke_prefill_decode_consistency(arch):
 
 
 @pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "grok-1-314b",
-                                  "deepseek-v3-671b", "zamba2-2.7b",
-                                  "rwkv6-3b"])
+                                  "deepseek-v3-671b", "deepseek-v3-671b-ep32",
+                                  "zamba2-2.7b", "rwkv6-3b"])
 def test_decode_matches_teacher_forcing(arch):
     """Prefill(S) then decode(token S) must equal full forward at pos S."""
     cfg = get_arch(arch).reduced()
@@ -83,7 +83,8 @@ def test_decode_matches_teacher_forcing(arch):
 
 
 @pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "starcoder2-15b",
-                                  "deepseek-v3-671b"])
+                                  "deepseek-v3-671b",
+                                  "deepseek-v3-671b-ep32"])
 def test_decode_writes_each_row_at_its_own_length(arch):
     """Two rows prefilled to different lengths, then decoded together:
     each row's logits are its own teacher-forced ones, and each step

@@ -23,9 +23,9 @@ mesh = make_test_mesh((2, 4), ('data', 'model'))
 dist = make_dist(mesh)
 
 key = jax.random.key(0)
-p_local = moe_init(key, cfg, jnp.float32, 1)      # [1, 8, d, ff]
-p_shard = moe_init(key, cfg, jnp.float32, 4)      # [4, 2, d, ff]
-# same logical experts: reshape local [1,8,...] -> [4,2,...]
+p_local = moe_init(key, cfg, jnp.float32, 1)      # [d, 8*ff]
+p_shard = moe_init(key, cfg, jnp.float32, 4)      # [d, 4*2*ff]
+# same logical experts: slot m*2+j is device m's j-th expert
 p_shard = dict(p_shard)
 for k in ('up', 'down', 'gate'):
     p_shard[k] = p_local[k].reshape(p_shard[k].shape)

@@ -140,3 +140,26 @@ def test_compiled_steps_carry_the_names_the_trace_reads(executor, step):
     # jit_decode programs
     text = getattr(executor, step + "_j").as_text()
     assert re.match(rf"HloModule jit_{step}\b", text), text[:200]
+
+
+SCOPES = ("mla", "moe.route", "moe.experts", "moe.shared", "mlp.dense")
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_compiled_steps_carry_the_layer_scopes(step):
+    """The op metadata of the compiled steps names the layer each op runs
+    in (``jax.named_scope``), so that a trace reader can add up device
+    time by scope: latent attention, the router, the held experts, the
+    shared expert and the dense MLP of the leading layers."""
+    cfg = get_arch("deepseek-v3-671b-ep32").reduced()
+    model = build_model(cfg, no_dist())
+    params = model.abstract_params()
+    prefill, decode = serve.jit_steps(serve.serve_steps(model, PROMPT + 4))
+    toks = jax.ShapeDtypeStruct((1, PROMPT), jax.numpy.int32)
+    tok, _, cache, lengths = jax.eval_shape(prefill, params, toks)
+    fn, args = {"prefill": (prefill, (params, toks)),
+                "decode": (decode, (params, cache, tok, lengths))}[step]
+    names = re.findall(r'op_name="([^"]*)"', fn.lower(*args).compile()
+                       .as_text())
+    for scope in SCOPES:
+        assert any(f"/{scope}/" in n for n in names), scope

@@ -9,6 +9,8 @@ that runs this file loads the TPU library. The persistent compilation
 cache is off around these compiles: an entry compiled for a described
 device cannot be read back without one.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -80,6 +82,27 @@ def test_qwen_decode_step_compiles(qwen, one_chip):
     compiled = decode.lower(params, cache, tok, lengths).compile()
     _fits(compiled)
     assert_updates_cache_in_place(compiled, cache)
+
+
+def test_deepseek_share_decode_reads_experts_in_place(one_chip):
+    """The DeepSeek-V3 share's decode at published widths (the benchmark
+    cell's 3 dense + 4 MoE layers and vocabulary slice): the donated latent
+    cache is updated in place, and no layer's held experts are copied. A
+    layer loop that slices them out of the stack, or an expert axis in the
+    tiled minor pair of a matrix, copies gigabytes every call."""
+    cfg = dataclasses.replace(get_arch("deepseek-v3-671b-ep32"), n_layers=7,
+                              vocab=16160)
+    model = build_model(cfg, no_dist())
+    params = jax.tree.map(lambda s: placed(s, one_chip),
+                          model.abstract_params())
+    toks = placed(jax.ShapeDtypeStruct((1, PROMPT), jnp.int32), one_chip)
+    prefill, decode = jit_steps(serve_steps(model, MAX_SEQ))
+    tok, _, cache, lengths = jax.tree.map(
+        lambda s: placed(s, one_chip), jax.eval_shape(prefill, params, toks))
+    compiled = decode.lower(params, cache, tok, lengths).compile()
+    _fits(compiled)
+    assert_updates_cache_in_place(compiled, cache)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
 def _kernel_hlo(fn, *shapes, sharding):
