@@ -34,7 +34,7 @@ from repro.analysis import derived, segment, tag_heavy
 from repro.configs import get_arch
 from repro.dist.context import no_dist
 from repro.launch.compile_cache import enable_compile_cache
-from repro.models.api import build_model
+from repro.models.api import PER_ROW_CACHE_FAMILIES, build_model
 from repro.sched import (ClusterConfig, ClusterEngine, ClusterTopology,
                          SpecializedPolicy, Topology)
 from repro.sched.engine import Engine, Request, ServeConfig
@@ -51,7 +51,11 @@ def serve_steps(model, max_seq: int):
     """The two step functions the executor compiles, each returning
     ``(next_token, all_logits_finite, cache, lengths)``:
     ``prefill(params, tokens)`` fills a fresh ``max_seq`` cache, and
-    ``decode(params, cache, token, lengths)`` appends one token."""
+    ``decode(params, cache, token, lengths)`` appends one token. Decode
+    takes one cache of a batch with tokens ``[B,1]`` and lengths ``[B]``,
+    or per-row sequences of caches, ``[1,1]`` tokens and ``[1]`` lengths,
+    which it returns as such: the rows advance in one step that reads the
+    weights once, each over its own cache."""
     def prefill(p, toks):
         cache = model.init_cache(p, {"tokens": toks}, toks.shape[0],
                                  max_seq)
@@ -60,18 +64,32 @@ def serve_steps(model, max_seq: int):
                 jnp.full((toks.shape[0],), toks.shape[1], jnp.int32))
 
     def decode(p, cache, tok, lengths):
+        rows = not isinstance(cache, dict)
+        if rows:
+            tok, lengths = jnp.concatenate(tok), jnp.concatenate(lengths)
         logits, cache = model.decode_step(p, cache, tok, lengths)
-        return (*_greedy(logits), cache, lengths + 1)
+        tok, ok = _greedy(logits)
+        lengths = lengths + 1
+        if rows:
+            tok, lengths = (tuple(a[i:i + 1] for i in range(a.shape[0]))
+                            for a in (tok, lengths))
+        return tok, ok, cache, lengths
 
     return prefill, decode
 
 
 def jit_steps(steps):
     """``serve_steps``' two functions jitted as the executor runs them:
-    decode donates its cache (argument 1), which it then updates in place
+    decode donates its caches (argument 1), which it then updates in place
     instead of copying. A donated cache is never read again."""
     prefill, decode = steps
     return jax.jit(prefill), jax.jit(decode, donate_argnums=1)
+
+
+def round_sizes(cap: int) -> tuple:
+    """The sizes the executor compiles a decode round for: the powers of
+    two below ``cap``, and ``cap``."""
+    return tuple(1 << i for i in range((cap - 1).bit_length())) + (cap,)
 
 
 def placed(s, sharding):
@@ -80,12 +98,12 @@ def placed(s, sharding):
 
 
 def _call(fn, *args):
-    """``fn(*args)``, a compiled step, once its token is ready: the call
+    """``fn(*args)``, a compiled step, once its tokens are ready: the call
     annotated ``executor.dispatch``, the wait ``executor.sync``."""
     with jax.profiler.TraceAnnotation("executor.dispatch"):
         out = fn(*args)
     with jax.profiler.TraceAnnotation("executor.sync"):
-        out[0].block_until_ready()
+        jax.block_until_ready(out[0])
     return out
 
 
@@ -109,12 +127,22 @@ class RealModelExecutor:
     the host's time in a call shows on the trace's host plane, beside
     the device's programs.
 
-    Decode donates the cache it is given: ``decode`` pops a request's
-    state before the call and keeps only the cache the call returns.
+    A decode round is one device call: the round's requests advance in
+    one program that reads the weights once, each over its own cache.
+    The program is compiled for each of ``round_sizes(batch)``; a round
+    runs the smallest that holds it, its spare rows filled with scratch
+    caches that the executor owns, whose outputs are dropped. Decode
+    donates the caches it is given: ``decode`` pops each request's state
+    before the call and keeps only the cache the call returns.
     """
 
     def __init__(self, model, params, vocab: int, prompt_len: int,
-                 max_seq: int, device, seed: int = 0):
+                 max_seq: int, device, seed: int = 0, batch: int = 8):
+        if model.family not in PER_ROW_CACHE_FAMILIES:
+            raise ValueError(
+                f"{model.family!r} models decode one cache of a batch; the "
+                "executor decodes a round over per-request caches, which "
+                f"only {sorted(PER_ROW_CACHE_FAMILIES)} models take")
         self.vocab = vocab
         self.prompt_len = prompt_len
         self.seed = seed
@@ -124,28 +152,53 @@ class RealModelExecutor:
         self.tokens = {}         # rid -> emitted tokens, device [1,1] each
         self._finite = []        # per step: every logit finite (device)
         self._steps = serve_steps(model, max_seq)
-        self.prefill_j = self.decode_j = None
+        self.sizes = round_sizes(batch)
+        self.prefill_j = None
+        self.decode_j = {}       # round size -> compiled decode
+        self._spare = []         # scratch caches that pad a round
+        self._spare_row = None   # the token and length of a spare row
 
     def compile(self) -> float:
-        """Compile prefill and decode for this device, and run each
-        once, ahead of the run: no compile or first-call cost lands in
-        a measured service time. Returns the seconds it took."""
+        """Compile prefill, and decode for each round size, for this
+        device, and run each once, ahead of the run: no compile or
+        first-call cost lands in a measured service time. Returns the
+        seconds it took."""
         t0 = time.perf_counter()
         prefill, decode = jit_steps(self._steps)
         on = SingleDeviceSharding(self.device)
         toks = jax.ShapeDtypeStruct((1, self.prompt_len), jnp.int32,
                                     sharding=on)
         self.prefill_j = prefill.lower(self.params, toks).compile()
+        # the device runs prefill's first call while the host compiles
+        # the rounds
+        first = self.prefill_j(self.params, jax.device_put(
+            np.zeros(toks.shape, np.int32), self.device))
         tok, _, cache, lengths = jax.tree.map(
             lambda s: placed(s, on), jax.eval_shape(prefill, self.params,
                                                     toks))
-        self.decode_j = decode.lower(self.params, cache, tok,
-                                     lengths).compile()
-        tok, _, cache, lengths = self.prefill_j(
-            self.params, jax.device_put(np.zeros(toks.shape, np.int32),
-                                        self.device))
-        self.decode_j(self.params, cache, tok, lengths)[0].block_until_ready()
+        self.decode_j = {b: decode.lower(self.params, (cache,) * b,
+                                         (tok,) * b, (lengths,) * b).compile()
+                         for b in self.sizes}
+        tok, _, _, lengths = first
+        self._spare_row = (tok, lengths)
+        caches = [jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype,
+                                                   device=on), cache)
+                  for _ in range(self.sizes[-1])]
+        for b in self.sizes:
+            caches[:b] = self.decode_j[b](self.params, tuple(caches[:b]),
+                                          (tok,) * b, (lengths,) * b)[2]
+        jax.block_until_ready(caches)
+        pads = max(self._size(n) - n for n in range(1, self.sizes[-1] + 1))
+        self._spare = caches[:pads]
         return time.perf_counter() - t0
+
+    def _size(self, n: int) -> int:
+        """The compiled round size that holds ``n`` sequences."""
+        for b in self.sizes:
+            if b >= n:
+                return b
+        raise ValueError(f"a round of {n} sequences exceeds the executor's "
+                         f"largest, {self.sizes[-1]}")
 
     def prompt(self, rid: int) -> np.ndarray:
         rng = np.random.default_rng((self.seed, rid))
@@ -171,16 +224,23 @@ class RealModelExecutor:
 
     def decode(self, batch, pool: str, ndev: int) -> float:
         t0 = time.perf_counter()
-        for req in batch:
-            cache, tok, lengths = self.state.pop(req.rid)
-            tok, ok, cache, lengths = _call(self.decode_j, self.params,
-                                            cache, tok, lengths)
+        n = len(batch)
+        if not n:
+            return 0.0
+        pad = self._size(n) - n
+        states = [self.state.pop(r.rid) for r in batch]
+        states += [(c, *self._spare_row) for c in self._spare[:pad]]
+        caches, toks, lengths = zip(*states)
+        toks, ok, caches, lengths = _call(self.decode_j[n + pad],
+                                          self.params, caches, toks, lengths)
+        self._spare[:pad] = caches[n:]
+        self._finite.append(ok)
+        for req, cache, tok, length in zip(batch, caches, toks, lengths):
             self.tokens[req.rid].append(tok)
-            self._finite.append(ok)
             # a request that finishes with this token drops its KV cache,
             # so executor memory scales with concurrency, not total served
             if req.generated + 1 < req.max_new:
-                self.state[req.rid] = (cache, tok, lengths)
+                self.state[req.rid] = (cache, tok, length)
         return (time.perf_counter() - t0) * 1e3
 
     def emitted(self) -> dict:
@@ -308,7 +368,8 @@ def run_engine(args, cfg, model, params) -> ServeRun:
 
     topo = Topology.serving(n_devices=2, prefill_devices=1)
     ex = RealModelExecutor(model, params, cfg.vocab, P, P + N,
-                           jax.devices()[0], seed=args.seed)
+                           jax.devices()[0], seed=args.seed,
+                           batch=args.batch)
     executors = {"engine": ex}
     compile_s = _compile(executors)
     reqs = _requests(args)
@@ -358,7 +419,8 @@ def run_cluster(args, cfg, model, params) -> ServeRun:
     executors = {}
     for spec, dev in zip(cluster.shards, shard_devices(args.shards)):
         executors[spec.name] = RealModelExecutor(
-            model, params, cfg.vocab, P, P + N, dev, seed=args.seed)
+            model, params, cfg.vocab, P, P + N, dev, seed=args.seed,
+            batch=args.batch)
         print(f"[serve] {spec.name}: {spec.topology.n_units} pool units "
               f"on {dev.platform}:{dev.id}")
     compile_s = _compile(executors)

@@ -280,6 +280,10 @@ BUILDERS = {
     "dense": _build_lm, "moe": _build_lm, "vlm": _build_lm,
     "hybrid": _build_hybrid, "ssm": _build_rwkv, "audio": _build_encdec,
 }
+# the families whose ``decode_step`` also takes a sequence of caches, one
+# per group of rows (``attention.row_groups``), such as one per request
+PER_ROW_CACHE_FAMILIES = frozenset(
+    f for f, b in BUILDERS.items() if b is _build_lm)
 
 
 def build_model(cfg: ArchConfig, dist: DistContext = no_dist()) -> Model:

@@ -6,7 +6,9 @@ Each block exposes:
   init_cache(cfg, batch, max_seq, dtype) -> cache
   prefill(params, x, cfg, cache, positions) -> (y, cache)  (writes cache)
   decode_token(params, x, cfg, cache, lengths) -> (y, new) (x is [B,1,d];
-      ``new`` is the token's cache entries, which the caller writes)
+      ``new`` is the token's cache entries, which the caller writes;
+      ``cache`` is one layer's cache or a sequence of them, one per group
+      of rows, as ``row_groups`` splits them)
   decode(params, x, cfg, cache, lengths) -> (y, cache)     (GQA: the same,
       written into one layer's cache, for the hybrid and enc-dec models)
 
@@ -106,19 +108,38 @@ def write_tokens(cache, new, lengths, stacked: bool = False):
             for k, buf in cache.items()}
 
 
+def row_groups(cache, stacked: bool = False) -> list:
+    """``cache``, one cache ``{name: [B,...]}`` (``stacked``: ``[L,B,...]``)
+    or a sequence of them, as ``[(rows, cache)]``: each cache with the
+    slice of the step's rows it holds, the caches' batches stacked in the
+    sequence's order."""
+    caches = [cache] if isinstance(cache, dict) else cache
+    out, at = [], 0
+    for c in caches:
+        n = next(iter(c.values())).shape[int(stacked)]
+        out.append((slice(at, at + n), c))
+        at += n
+    return out
+
+
 def gqa_decode_token(p, x, cfg: ArchConfig, cache, lengths):
     """x: [B,1,d]; lengths[b] = number of tokens BEFORE this one. Attends
     over the cache's first ``lengths[b]`` positions and the token itself,
     and returns ``(y, new)``: the token's ``{"k", "v"}`` ``[B,KVH,D]``,
-    which it leaves to the caller to write (``write_tokens``)."""
+    which it leaves to the caller to write (``write_tokens``). The
+    projections run once over the batch; each row attends over its own
+    cache of ``row_groups(cache)``."""
     B = x.shape[0]
     cdt = dt(cfg.compute_dtype)
+    groups = row_groups(cache)
     positions = lengths[:, None]                            # [B,1]
     q, k, v = _qkv(p, x, cfg, positions)
-    new = {"k": k[:, 0].astype(cache["k"].dtype),
-           "v": v[:, 0].astype(cache["v"].dtype)}
-    o = decode_attention(q, cache["k"], cache["v"], lengths,
-                         k_new=new["k"], v_new=new["v"], compute_dtype=cdt)
+    kv_dt = groups[0][1]["k"].dtype
+    new = {"k": k[:, 0].astype(kv_dt), "v": v[:, 0].astype(kv_dt)}
+    o = jnp.concatenate([
+        decode_attention(q[r], c["k"], c["v"], lengths[r], k_new=new["k"][r],
+                         v_new=new["v"][r], compute_dtype=cdt)
+        for r, c in groups])
     return dense(p["wo"], o.reshape(B, 1, -1), cdt), new
 
 
@@ -240,7 +261,8 @@ def mla_decode_token(p, x, cfg: ArchConfig, cache, lengths):
     """Absorbed-form decode: score/readout in the compressed latent space,
     over the cache's first ``lengths[b]`` positions and the token itself.
     Returns ``(y, new)`` as ``gqa_decode_token`` does, ``new`` the token's
-    ``{"c_kv": [B,lora], "k_rope": [B,rope]}``."""
+    ``{"c_kv": [B,lora], "k_rope": [B,rope]}``; as there, each row reads
+    its own cache of ``row_groups(cache)``."""
     with jax.named_scope("mla"):
         return _mla_decode_token(p, x, cfg, cache, lengths)
 
@@ -251,33 +273,39 @@ def _mla_decode_token(p, x, cfg: ArchConfig, cache, lengths):
     H = cfg.n_heads
     cdt = dt(cfg.compute_dtype)
     positions = lengths[:, None]
+    groups = row_groups(cache)
     q_nope, q_rope = _mla_q(p, x, cfg, positions)           # [B,1,H,*]
     c_kv_new, k_rope_new = _mla_latent(p, x, cfg, positions)
-    ckv, krp = cache["c_kv"], cache["k_rope"]
-    new = {"c_kv": c_kv_new[:, 0].astype(ckv.dtype),
-           "k_rope": k_rope_new[:, 0].astype(krp.dtype)}
+    new = {"c_kv": c_kv_new[:, 0].astype(groups[0][1]["c_kv"].dtype),
+           "k_rope": k_rope_new[:, 0].astype(groups[0][1]["k_rope"].dtype)}
     w_uk, w_uv = _mla_wkv_b_split(p, cfg)
     # absorb W_uk into q: q_lat [B,1,H,lora]
     q_lat = jnp.einsum("bshn,lhn->bshl", q_nope.astype(cdt), w_uk.astype(cdt),
                        preferred_element_type=jnp.float32)
 
-    def scores(c, r):                                       # -> [B,H,1,T]
-        return (jnp.einsum("bshl,btl->bhst", q_lat.astype(cdt), c.astype(cdt),
-                           preferred_element_type=jnp.float32)
-                + jnp.einsum("bshr,btr->bhst", q_rope.astype(cdt),
-                             r.astype(cdt), preferred_element_type=jnp.float32)
+    def scores(r, ckv, krp):                                # -> [b,H,1,T]
+        return (jnp.einsum("bshl,btl->bhst", q_lat[r].astype(cdt),
+                           ckv.astype(cdt), preferred_element_type=jnp.float32)
+                + jnp.einsum("bshr,btr->bhst", q_rope[r].astype(cdt),
+                             krp.astype(cdt),
+                             preferred_element_type=jnp.float32)
                 ) * mla_scale(cfg)
 
-    def readout(pattn, c):                                  # -> [B,1,H,lora]
+    def readout(pattn, c):                                  # -> [b,1,H,lora]
         return jnp.einsum("bhst,btl->bshl", pattn.astype(cdt), c.astype(cdt),
                           preferred_element_type=jnp.float32)
 
-    Smax = ckv.shape[1]
-    valid = (jnp.arange(Smax)[None, :] < lengths[:, None])[:, None, None, :]
-    s = jnp.where(valid, scores(ckv, krp), -1e30)
-    s_new = scores(new["c_kv"][:, None], new["k_rope"][:, None])
-    pattn, p_new = softmax_with_token(s, s_new)
-    o_lat = readout(pattn, ckv) + readout(p_new, new["c_kv"][:, None])
+    def attend(r, ckv, krp):                                # one group's rows
+        Smax = ckv.shape[1]
+        valid = jnp.arange(Smax)[None, :] < lengths[r][:, None]
+        s = jnp.where(valid[:, None, None, :], scores(r, ckv, krp), -1e30)
+        c_new = new["c_kv"][r][:, None]
+        pattn, p_new = softmax_with_token(
+            s, scores(r, c_new, new["k_rope"][r][:, None]))
+        return readout(pattn, ckv) + readout(p_new, c_new)
+
+    o_lat = jnp.concatenate([attend(r, c["c_kv"], c["k_rope"])
+                             for r, c in groups])
     o = jnp.einsum("bshl,lhv->bshv", o_lat.astype(cdt), w_uv.astype(cdt),
                    preferred_element_type=jnp.float32)      # [B,1,H,v]
     y = dense(p["wo"], o.reshape(B, 1, H * m.v_head_dim).astype(cdt), cdt)

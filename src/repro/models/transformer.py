@@ -348,19 +348,27 @@ def lm_decode_step(params, cache, tokens, lengths, cfg: ArchConfig,
                    dist: DistContext = no_dist()):
     """tokens [B,1], lengths [B] -> (logits [B,V], cache).
 
-    The layer loop only reads the cache: each layer attends over its slice
-    and over the new token, and hands the token's entries out of the loop,
-    which writes all layers' at once at ``(:, b, lengths[b])``. A caller
-    that donates the cache so has it updated in place, not copied."""
+    ``cache`` is one cache of batch B, or a sequence of caches whose
+    batches stack into the B rows, such as one cache of batch 1 per
+    request (``attention.row_groups``); the step returns the same. The
+    embedding, projections, FFN and unembedding run once over the B rows,
+    so each weight is read once; each row attends over its own cache.
+
+    The layer loop only reads the caches: each layer attends over its
+    slice and over the new token, and hands the token's entries out of the
+    loop, which writes all layers' at once at ``(:, b, lengths[b])``. A
+    caller that donates the caches so has them updated in place, not
+    copied."""
     cdt = dt(cfg.compute_dtype)
     x = jnp.take(params["embed"], tokens, axis=0).astype(cdt)
     decode = (attn.mla_decode_token if cfg.attention == "mla"
               else attn.gqa_decode_token)
+    groups = attn.row_groups(cache, stacked=True)
 
     def body(x, sl):
         p_l, layer = sl
         h = apply_norm(p_l["norm1"], x, cfg.norm, cfg.norm_eps)
-        cache_l = {k: c[layer] for k, c in cache.items()}
+        cache_l = [{k: c[layer] for k, c in g.items()} for _, g in groups]
         y, new = decode(p_l["attn"], h, cfg, cache_l, lengths)
         x = x + y
         h = apply_norm(p_l["norm2"], x, cfg.norm, cfg.norm_eps)
@@ -381,8 +389,10 @@ def lm_decode_step(params, cache, tokens, lengths, cfg: ArchConfig,
     x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     w = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = unembed(x, w, cdt)
-    return logits[:, 0, :], attn.write_tokens(cache, new, lengths,
-                                              stacked=True)
+    written = tuple(attn.write_tokens(g, {k: v[:, r] for k, v in new.items()},
+                                      lengths[r], stacked=True)
+                    for r, g in groups)
+    return logits[:, 0, :], written[0] if isinstance(cache, dict) else written
 
 
 # ------------------------------------------------- optional: MTP head

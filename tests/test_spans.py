@@ -9,7 +9,7 @@ import sys
 import jax
 import pytest
 
-from helpers import REPO
+from helpers import REPO, Rounds, reported_times
 from repro.configs import get_arch
 from repro.dist.context import no_dist
 from repro.launch import serve
@@ -27,7 +27,7 @@ def executor():
     model = build_model(cfg, no_dist())
     params = jax.jit(model.init)(jax.random.key(0))
     ex = RealModelExecutor(model, params, cfg.vocab, PROMPT,
-                           PROMPT + MAX_NEW, jax.devices()[0])
+                           PROMPT + MAX_NEW, jax.devices()[0], batch=BATCH)
     ex.compile()
     return ex
 
@@ -85,11 +85,15 @@ def test_prefill_annotates_upload_dispatch_and_sync(executor, annotations):
 def test_each_decode_call_is_one_dispatch_and_one_sync(executor,
                                                        annotations):
     executor.state.clear()
-    m = engine(executor).run(requests(3))
+    rounds = Rounds()
+    with reported_times(executor):
+        m = engine(executor).run(requests(3), oracle=rounds)
     assert m.completed == 3
     names = [n for k, n in annotations if k == "enter"]
     assert [n for k, n in annotations if k == "exit"] == names
-    calls = 3 + 3 * (MAX_NEW - 1)      # a prefill each, then decode calls
+    assert sum(rounds.sizes) == 3 * (MAX_NEW - 1)
+    assert max(rounds.sizes) == BATCH
+    calls = 3 + len(rounds.sizes)      # a prefill each, then one a round
     assert names.count("executor.upload") == 3
     assert names.count("executor.dispatch") == calls
     assert names.count("executor.sync") == calls
@@ -137,9 +141,14 @@ def test_serve_prints_no_simulated_license(mode, capsys):
 @pytest.mark.parametrize("step", ["prefill", "decode"])
 def test_compiled_steps_carry_the_names_the_trace_reads(executor, step):
     # bench/trace.py finds the steps' device time as jit_prefill and
-    # jit_decode programs
-    text = getattr(executor, step + "_j").as_text()
-    assert re.match(rf"HloModule jit_{step}\b", text), text[:200]
+    # jit_decode programs: the decode of every round size
+    programs = {"prefill": {1: executor.prefill_j},
+                "decode": executor.decode_j}[step]
+    if step == "decode":
+        assert tuple(programs) == serve.round_sizes(BATCH)
+    for compiled in programs.values():
+        text = compiled.as_text()
+        assert re.match(rf"HloModule jit_{step}\b", text), text[:200]
 
 
 SCOPES = ("mla", "moe.route", "moe.experts", "moe.shared", "mlp.dense")
@@ -157,8 +166,10 @@ def test_compiled_steps_carry_the_layer_scopes(step):
     prefill, decode = serve.jit_steps(serve.serve_steps(model, PROMPT + 4))
     toks = jax.ShapeDtypeStruct((1, PROMPT), jax.numpy.int32)
     tok, _, cache, lengths = jax.eval_shape(prefill, params, toks)
+    # decode as the executor runs a round of two, one cache each
+    rows = [(x,) * 2 for x in (cache, tok, lengths)]
     fn, args = {"prefill": (prefill, (params, toks)),
-                "decode": (decode, (params, cache, tok, lengths))}[step]
+                "decode": (decode, (params, *rows))}[step]
     names = re.findall(r'op_name="([^"]*)"', fn.lower(*args).compile()
                        .as_text())
     for scope in SCOPES:
