@@ -1,5 +1,6 @@
 """TPU adaptation benchmark: device-pool specialization for serving
-(DESIGN.md §2.2) — the paper's Fig. 5 analogue on an LLM workload.
+(prefill and decode on separate device pools) — the paper's Fig. 5
+analogue on an LLM workload.
 
 Baseline: ``SharedBaselinePolicy`` over one shared pool, chunked prefill
 interleaved with decode (every prefill stalls all co-located decodes —

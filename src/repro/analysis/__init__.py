@@ -24,11 +24,12 @@ This package works at sub-function granularity on jaxprs:
     (no jax / scheduler imports, so ``sched.workload`` and the replay
     worker processes can consume it cheaply);
   * :mod:`repro.analysis.lint` — intermittency lint: license-thrash
-    candidates and untagged heavy entrypoints, with a committed
-    baseline and a CI drift gate.
+    candidates, and heavy entrypoints the committed artifact does not
+    tag (a stale ``derived.json``), with a committed baseline and a CI
+    drift gate.
 
-``repro.core.static_analysis`` remains as a compat shim over this
-package.
+The package is a tool: the serving path reads only ``derived.json``'s
+license levels, through :mod:`repro.analysis.derived`.
 
 Attribute access is lazy (PEP 562): importing ``repro.analysis.derived``
 must NOT pull jax into the scheduler's import path.

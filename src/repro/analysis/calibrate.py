@@ -10,7 +10,7 @@ Everything downstream consumes the artifact through
 :mod:`repro.analysis.derived`: ``sched.workload`` registers one
 ``zoo/<arch>`` scenario per architecture, ``core.workloads.trace_tasks``
 reads the per-scenario cycle scaling, and ``launch.serve`` uses the
-derived engine frequency config and tag set.
+derived engine frequency config.
 
 Derivations (all documented here because the artifact is committed):
 

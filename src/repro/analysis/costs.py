@@ -29,7 +29,7 @@ operands + results at their actual dtypes (``np.dtype(..).itemsize``).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Optional, Tuple
+from typing import List, Optional
 
 import numpy as np
 
@@ -196,7 +196,3 @@ def jaxpr_cost(jaxpr, cfg: CostConfig = CostConfig(),
         total = total + eqn_cost(eqn, cfg, warnings)
     return total
 
-
-def cost_tuple(c: EqnCost) -> Tuple[float, float, float]:
-    """(mxu_flops, total_flops, bytes) — the legacy triple."""
-    return c.mxu_flops, c.flops, c.bytes

@@ -18,12 +18,12 @@ Two finding kinds, both ranked by severity:
   trip.
 
 * ``untagged-heavy-entrypoint`` — an entrypoint the analyzer tags heavy
-  *today* that is missing from the committed ``derived.json`` tag set.
-  ``launch/serve.py`` drives its phase tagging from the committed
-  artifact, so this is exactly the set of entrypoints serve would run
-  untagged (no license pre-grant, detect-then-throttle path) — the bug
-  class the paper's mechanism exists to avoid. Fails ``--check-baseline``
-  unconditionally; fix by rerunning ``calibrate --update``.
+  *today* that is missing from the committed ``derived.json`` tag set:
+  the artifact no longer matches the code it was derived from. It
+  guards the artifact's consistency; serving does not read the tags
+  (the engine's policy places prefill by its work kind). Fails
+  ``--check-baseline`` unconditionally; fix by rerunning
+  ``calibrate --update``.
 
 ``--check-baseline`` also fails when the finding set drifts from the
 committed ``lint_baseline.json`` — new thrash candidates introduced by
@@ -102,8 +102,8 @@ def untagged_findings(workload: str, fresh_tags: List[str],
             kind="untagged-heavy-entrypoint", workload=workload,
             entrypoint=name, severity=heavy_us.get(name, 0.0) or 1.0,
             detail=(f"analyzer tags '{name}' heavy but derived.json does "
-                    f"not — launch.serve would run it untagged "
-                    f"(detect-then-throttle); rerun calibrate --update")))
+                    f"not — the artifact is stale; rerun calibrate "
+                    f"--update")))
     return out
 
 

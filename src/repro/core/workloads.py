@@ -5,7 +5,7 @@ request: parse (scalar) -> SSL_read (annotated crypto) -> brotli
 compression (scalar, dominant) -> SSL_write (annotated crypto).
 Closed-loop connection tasks saturate the server like wrk2 at capacity.
 
-Calibration (documented in EXPERIMENTS.md §Fig5): the paper's operating
+Calibration (Fig. 5): the paper's operating
 point is 12 server cores and ~55,000 task-type changes/s, i.e. ~1,146
 requests/core/s with 4 annotated SSL calls each. Only a fraction of SSL
 write sections sustain a dense-enough heavy mix to trigger a license

@@ -7,7 +7,7 @@ duplicates GQA heads (BlockSpec index map folds q head -> kv head).
 
 This kernel is the serving hot path the device-pool scheduler tags as
 "light"/memory-bound (decode), in contrast to flash_attention (prefill,
-MXU-bound) — the two workload classes of DESIGN.md §2.2.
+MXU-bound) — the two work kinds ``repro.sched`` places on separate pools.
 """
 from __future__ import annotations
 

@@ -6,7 +6,7 @@ dimension, so the (m, l, acc) running state lives in VMEM scratch and
 persists across the kv-block iterations of one q block; the causal upper
 triangle is skipped with ``pl.when`` (on real hardware the skipped block
 issues no MXU work — this is the half-FLOPs advantage over the XLA
-reference path, see EXPERIMENTS.md §Perf).
+reference path in ``repro.models.layers``).
 
 Layout: q [BH, S, D] (B*H fused), k/v [BKV, S, D]; GQA maps q head bh to
 kv head bh // group via the BlockSpec index map — no repeated kv in HBM.

@@ -6,11 +6,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512 " \
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-For each cell we build abstract (ShapeDtypeStruct) params / optimizer
-state / caches, attach NamedShardings, ``.lower().compile()`` the step,
-and record ``memory_analysis()`` / ``cost_analysis()`` / parsed collective
-bytes into a JSON results file consumed by EXPERIMENTS.md and the
-roofline tables.
+A compile rehearsal on fake host devices: for each cell we build
+abstract (ShapeDtypeStruct) params / optimizer state / caches, attach
+NamedShardings, ``.lower().compile()`` the step, and record
+``memory_analysis()`` / ``cost_analysis()`` / parsed collective bytes
+and the cell's roofline terms into a JSON results file. Nothing runs:
+the numbers are the compiler's and the roofline model's, not a
+device's.
 
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen1.5-0.5b \
       --shape train_4k --mesh single
@@ -35,8 +37,8 @@ from repro.roofline import hlo_cost
 from repro.train.loop import init_opt_state, jit_train_step
 from repro.train.optimizer import OptConfig
 
-# tokens-per-device memory pressure -> grad accumulation (recorded in
-# EXPERIMENTS.md; the batch is unchanged, microbatches scan sequentially)
+# tokens-per-device memory pressure -> grad accumulation (the batch is
+# unchanged, microbatches scan sequentially)
 GRAD_ACCUM = {
     "chameleon-34b": 8,
     "codeqwen1.5-7b": 4,

@@ -1,17 +1,13 @@
 """Serving driver: a real model behind the specialization engine.
 
-Runs jitted prefill/decode of a model at its published width (or the
-CPU-sized config with ``--reduced``), driven by the event-driven engine
-(`repro.sched.engine`) — the same scheduler code the benchmarks
-exercise, with service times *measured* from the jitted calls instead of
-modelled. The annotation workflow runs end-to-end: the region analyzer
-(`repro.analysis`) segments the two step functions into phase
-timelines, the calibrated tag set from ``analysis/derived.json``
-(falling back to a fresh ``tag_heavy`` for uncalibrated archs) marks the
-heavy (AVX-analogue) phase, and the ``SpecializedPolicy`` confines it to
-the prefill pool of a two-pool ``Topology``. The engine's frequency
-domain likewise uses the calibrated per-arch license levels when
-available.
+Compiles a model's prefill and decode at its published width (or the
+CPU-sized config with ``--reduced``) into a ``RealModelExecutor`` on one
+device, and serves requests through the event-driven engine
+(`repro.sched.engine`): the ``SpecializedPolicy`` runs prefill, the heavy
+work, on the prefill pool of a two-pool ``Topology`` and decode on the
+other, and each step's service time is the measured duration of its
+device call. The engine's frequency domain takes the license levels
+calibrated for the arch in ``analysis/derived.json`` where there are any.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \\
       --requests 16 --prompt 64 --max-new 16 --reduced
@@ -19,7 +15,9 @@ available.
 ``--mode cluster`` runs N one-device engine shards behind the
 frequency-aware router (`repro.sched.cluster`): shard ``i`` holds its
 own copy of the parameters, its caches and its prompts on
-``jax.devices()[i]``.
+``jax.devices()[i]``, and ``--fault-plan`` injects a registered fault
+plan. Either mode prints the requests completed and their latency
+percentiles, and ``main`` returns a ``ServeRun``.
 """
 import argparse
 import dataclasses
@@ -30,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import SingleDeviceSharding
 
-from repro.analysis import derived, segment, tag_heavy
+from repro.analysis import derived
 from repro.configs import get_arch
 from repro.dist.context import no_dist
 from repro.launch.compile_cache import enable_compile_cache
@@ -263,41 +261,6 @@ class ServeRun:
     compile_s: float
 
 
-def identify_heavy_phase(model, params, batch: int, prompt: int,
-                         max_seq: int, arch: str = None):
-    """§3.3 identification workflow on the two step functions.
-
-    Segments both entrypoints into region timelines (from shapes only:
-    nothing is allocated) and returns ``(timelines, tags, source)``.
-    Tags come from the committed calibration artifact
-    (``analysis/derived.json``) when this arch was calibrated — the same
-    derivation the intermittency lint gates on, so serve can never
-    silently run an entrypoint the analyzer considers heavy untagged —
-    and from a fresh ``tag_heavy`` over the just-built timelines
-    otherwise."""
-    toks = jax.ShapeDtypeStruct((batch, prompt), jnp.int32)
-    cache = jax.eval_shape(
-        lambda p, t: model.init_cache(p, {"tokens": t}, batch, max_seq),
-        params, toks)
-
-    timelines = [
-        segment(lambda p, t, c: model.prefill(p, {"tokens": t}, c),
-                params, toks, cache, name="prefill"),
-        segment(lambda p, c, t, l: model.decode_step(p, c, t, l),
-                params, cache, jax.ShapeDtypeStruct((batch, 1), jnp.int32),
-                jax.ShapeDtypeStruct((batch,), jnp.int32),
-                name="decode_step"),
-    ]
-    committed = derived.workloads().get(arch) if arch else None
-    if committed:
-        tags = [t for t in committed["tags"]
-                if t in {tl.name for tl in timelines}]
-        src = "derived.json"
-    else:
-        tags, src = tag_heavy(timelines), "fresh tag_heavy"
-    return timelines, tags, src
-
-
 def engine_freq_config(arch: str):
     """The engine's ms-base frequency domain, with the license levels
     the calibration derived for this arch (falls back to the hand-tuned
@@ -308,15 +271,6 @@ def engine_freq_config(arch: str):
             ENGINE_FREQ_MS,
             freqs_ghz=tuple(derived.freq_levels_ghz(arch)))
     return ENGINE_FREQ_MS
-
-
-def _print_identification(timelines, tags, src) -> str:
-    print("[serve] region analysis (phase timelines):")
-    for tl in timelines:
-        print(tl.report())
-    heavy = tags[0] if tags else timelines[0].name
-    print(f"[serve] analyzer-derived heavy tags ({src}): {tags}")
-    return heavy
 
 
 def _requests(args) -> list:
@@ -341,12 +295,28 @@ def _requests(args) -> list:
     return reqs
 
 
-def _compile(executors: dict) -> float:
+def _executors(args, cfg, model, params, devices: dict) -> tuple:
+    """One executor per device of ``devices`` (name -> device), each
+    compiled and warmed for the run's prompt, length and batch. Returns
+    the executors by name and the seconds their compiling took."""
+    P = args.prompt
+    executors = {name: RealModelExecutor(model, params, cfg.vocab, P,
+                                         P + args.max_new, dev,
+                                         seed=args.seed, batch=args.batch)
+                 for name, dev in devices.items()}
     compile_s = sum(ex.compile() for ex in executors.values())
     print(f"[serve] compiled and warmed prefill+decode for "
           f"{len(executors)} executor(s) in {compile_s:.1f}s (set-up, "
           "outside the measured window)")
-    return compile_s
+    return executors, compile_s
+
+
+def _serve_config(args) -> ServeConfig:
+    """Each engine's config: whole prompts in one chunk, decode rounds
+    of at most ``--batch``, and the arch's frequency domain."""
+    return ServeConfig(prefill_chunk=args.prompt,
+                       decode_batch_max=args.batch,
+                       freq=engine_freq_config(args.arch))
 
 
 def _print_latency(s: dict, extra: str = ""):
@@ -359,25 +329,13 @@ def _print_latency(s: dict, extra: str = ""):
 def run_engine(args, cfg, model, params) -> ServeRun:
     """Real-model serving through the Policy/Topology engine on the
     first local device."""
-    P, N = args.prompt, args.max_new
-    timelines, tags, src = identify_heavy_phase(model, params, args.batch,
-                                                P, P + N, args.arch)
-    heavy = _print_identification(timelines, tags, src)
-    print(f"[serve] tagging {heavy!r} as the heavy (AVX-analogue) phase;"
-          " SpecializedPolicy confines it to the prefill pool\n")
-
-    topo = Topology.serving(n_devices=2, prefill_devices=1)
-    ex = RealModelExecutor(model, params, cfg.vocab, P, P + N,
-                           jax.devices()[0], seed=args.seed,
-                           batch=args.batch)
-    executors = {"engine": ex}
-    compile_s = _compile(executors)
+    N = args.max_new
+    executors, compile_s = _executors(args, cfg, model, params,
+                                      {"engine": jax.devices()[0]})
     reqs = _requests(args)
-    eng = Engine(topo, SpecializedPolicy(),
-                 cfg=ServeConfig(prefill_chunk=P,
-                                 decode_batch_max=args.batch,
-                                 freq=engine_freq_config(args.arch)),
-                 executor=ex)
+    eng = Engine(Topology.serving(n_devices=2, prefill_devices=1),
+                 SpecializedPolicy(), cfg=_serve_config(args),
+                 executor=executors["engine"])
     t0 = time.perf_counter()
     m = eng.run(reqs)               # no horizon: run to completion
     wall = time.perf_counter() - t0
@@ -408,26 +366,17 @@ def run_cluster(args, cfg, model, params) -> ServeRun:
     """Real-model cluster serving: N shards, each a two-pool engine
     with its own jitted executor on its own device, behind the
     SLO-aware router."""
-    P, N = args.prompt, args.max_new
-    timelines, tags, src = identify_heavy_phase(model, params, args.batch,
-                                                P, P + N, args.arch)
-    heavy = _print_identification(timelines, tags, src)
-    print(f"[serve] tagging {heavy!r} as the heavy phase; "
-          f"{args.shards}-shard cluster under {args.cluster_policy!r}\n")
-
+    print(f"[serve] {args.shards}-shard cluster under "
+          f"{args.cluster_policy!r}")
     cluster = ClusterTopology.homogeneous(args.shards, 2, 1)
-    executors = {}
+    devices = {}
     for spec, dev in zip(cluster.shards, shard_devices(args.shards)):
-        executors[spec.name] = RealModelExecutor(
-            model, params, cfg.vocab, P, P + N, dev, seed=args.seed,
-            batch=args.batch)
+        devices[spec.name] = dev
         print(f"[serve] {spec.name}: {spec.topology.n_units} pool units "
               f"on {dev.platform}:{dev.id}")
-    compile_s = _compile(executors)
+    executors, compile_s = _executors(args, cfg, model, params, devices)
     reqs = _requests(args)
-    ccfg = ClusterConfig(serve=ServeConfig(
-        prefill_chunk=P, decode_batch_max=args.batch,
-        freq=engine_freq_config(args.arch)))
+    ccfg = ClusterConfig(serve=_serve_config(args))
     eng = ClusterEngine(cluster, args.cluster_policy, cfg=ccfg,
                         executors=executors)
     plan = None

@@ -150,7 +150,7 @@ def mlp(p: dict, x: jnp.ndarray, act: str, glu: bool, compute_dtype) -> jnp.ndar
 # Pure-JAX blockwise online-softmax attention (the XLA reference path; the
 # Pallas kernel in repro.kernels.flash_attention is the TPU hot path).
 # Causal masking is applied per block; the XLA path pays full O(S^2) FLOPs
-# (block skipping happens in the Pallas kernel — see EXPERIMENTS.md).
+# (block skipping happens in the Pallas kernel).
 
 NEG_INF = -1e30
 

@@ -6,7 +6,8 @@ RMSNorm. The chunked scan carries the inter-chunk state h [B, nh, hd, N]
 so the forward is O(S·Q) memory instead of O(S^2).
 
 Decode keeps (conv window, h state) per layer — constant size, which is
-what makes zamba2/long_500k runnable (see DESIGN.md §Arch-applicability).
+what makes zamba2/long_500k runnable: the state does not grow with the
+context.
 """
 from __future__ import annotations
 

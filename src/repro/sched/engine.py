@@ -19,8 +19,9 @@ placement / steal / preemption / resize decision is delegated to a
     400-500 ns migration analogue). Exactly one handoff is counted per
     pool transfer.
 
-Service times come either from a :class:`PoolModel` (roofline terms of
-a dry-run cell; deterministic, used by benchmarks) or from a live
+Service times come either from a :class:`PoolModel` (fixed per-token
+and per-round terms; deterministic, used by the simulator's benchmarks
+and replays) or from a live
 ``executor`` that runs real jitted prefill/decode and reports measured
 durations (``launch/serve.py``).
 
@@ -628,27 +629,3 @@ class Engine:
         active[pool] = still + active[pool][cfg.decode_batch_max:]
         return end
 
-
-def pool_model_from_dryrun(results: dict, arch: str,
-                           mesh: str = "single") -> PoolModel:
-    """Derive per-chip service times from the dry-run roofline terms.
-
-    step_s is the per-device roofline time on `chips` devices, so one
-    chip-second per unit of work is step_s * chips; the engine divides by
-    its own pool size. Missing or failed dry-run entries fall back to the
-    default PoolModel."""
-    pre = results.get(f"{arch}|prefill_32k|{mesh}")
-    dec = results.get(f"{arch}|decode_32k|{mesh}")
-    if not (pre and dec and pre["status"] == dec["status"] == "ok"):
-        return PoolModel()
-    rp, rd = pre["roofline"], dec["roofline"]
-    chips = rp.get("chips", 256)
-    shape_tokens = 32 * 32768
-    prefill_chip_s_per_tok = rp["step_s"] * chips / shape_tokens
-    decode_chip_s_per_iter = rd["step_s"] * rd.get("chips", 256)
-    return PoolModel(
-        prefill_ms_per_ktok=max(prefill_chip_s_per_tok * 1e6, 1e-3),
-        decode_fixed_ms=max(decode_chip_s_per_iter * 1e3 * 0.2, 1e-3),
-        decode_ms_per_seq=max(decode_chip_s_per_iter * 1e3 * 0.8 / 128.0,
-                              1e-4),
-    )

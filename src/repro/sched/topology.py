@@ -183,7 +183,8 @@ class Topology:
     @staticmethod
     def serving(n_devices: int, prefill_devices: int) -> "Topology":
         """The serving shape: a ``prefill`` pool (heavy-capable) and a
-        ``decode`` pool that never prefills (DESIGN.md §2.2)."""
+        ``decode`` pool that never prefills: one interleaved prefill
+        would stall every decode that shares its devices."""
         return Topology.split(n_devices, prefill_devices,
                               heavy_name="prefill", light_name="decode")
 

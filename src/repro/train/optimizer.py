@@ -1,6 +1,6 @@
 """AdamW with global-norm clipping, cosine schedule, and configurable
-optimizer-state dtype (bf16 m/v for the 314B/671B configs — see
-EXPERIMENTS.md memory notes)."""
+optimizer-state dtype (bf16 m/v halves the optimizer state, for the
+314B/671B configs)."""
 from __future__ import annotations
 
 from dataclasses import dataclass

@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.analysis.costs import CostConfig, MXU_PRIMS, jaxpr_cost
+from repro.analysis.costs import CostConfig, jaxpr_cost
 from repro.analysis.differential import differential
 from repro.analysis.lint import lint_timeline, untagged_findings
 from repro.analysis.regions import (Region, RegionTimeline, segment,
@@ -289,10 +289,3 @@ def test_lint_baseline_committed_and_clean_of_untagged():
     # ranked: severities non-increasing
     sevs = [f["severity"] for f in base["findings"]]
     assert sevs == sorted(sevs, reverse=True)
-
-
-def test_shim_exports():
-    import repro.core.static_analysis as shim
-    assert shim.MXU_PRIMS == MXU_PRIMS
-    assert {"FunctionProfile", "analyze_jaxpr", "rank_functions",
-            "report"} <= set(shim.__all__)

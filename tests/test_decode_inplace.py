@@ -91,6 +91,17 @@ def test_engine_through_donating_executor_emits_plain_tokens(served):
         np.testing.assert_array_equal(got[rid], want)
 
 
+def test_executor_refuses_a_round_beyond_its_largest(served):
+    _, _, ex = served
+    ex.state.clear()
+    n = round_sizes(BATCH)[-1] + 1
+    reqs = [Request(rid=i, arrive_ms=0.0, prompt_len=PROMPT, max_new=2)
+            for i in range(n)]
+    with pytest.raises(ValueError, match="exceeds the executor's largest"):
+        ex.decode(reqs, "decode", 1)
+    assert ex.state == {}
+
+
 @pytest.mark.parametrize("arch", ["zamba2-2.7b", "rwkv6-3b",
                                   "whisper-large-v3"])
 def test_executor_refuses_models_without_per_request_caches(arch):

@@ -42,7 +42,8 @@ def test_nested_scan_multiplies():
 
 
 def test_xla_cost_analysis_undercounts_scans():
-    """The reason hlo_cost exists (documented in EXPERIMENTS.md)."""
+    """The reason hlo_cost exists: XLA's cost analysis counts a scan's
+    body once, whatever its trip count."""
     w = jnp.zeros((128, 128), jnp.float32)
 
     def mk(n):

@@ -3,14 +3,12 @@ flame graph + cross-check, and the adaptive policy (§4.3)."""
 import jax
 import jax.numpy as jnp
 
-from repro.analysis import segment, tag_heavy
+from repro.analysis import (analyze_jaxpr, rank_functions, report, segment,
+                            tag_heavy)
 from repro.core.adaptive import AdaptiveConfig, AdaptivePolicy
 from repro.core.muqss import SchedConfig
 from repro.core.perfcounters import CounterReport, cross_check
 from repro.core.simulator import Simulator
-# the identification workflow moved to repro.analysis; these imports go
-# through the compat shim on purpose — old callers must keep working
-from repro.core.static_analysis import analyze_jaxpr, rank_functions, report
 from repro.core.workloads import WebConfig, webserver_tasks
 
 
@@ -71,16 +69,6 @@ def test_static_analysis_scan_multiplies():
     p1 = analyze_jaxpr(once, jnp.zeros((4, 32)))
     p8 = analyze_jaxpr(scanned, jnp.zeros((4, 32)))
     assert abs(p8.mxu_flops / p1.mxu_flops - 8.0) < 0.01
-
-
-def test_shim_matches_new_package():
-    """repro.core.static_analysis is a shim over repro.analysis: same
-    objects, same numbers."""
-    import repro.analysis as na
-    import repro.core.static_analysis as shim
-    assert shim.analyze_jaxpr is na.analyze_jaxpr
-    assert shim.FunctionProfile is na.FunctionProfile
-    assert shim.MXU_PRIMS == na.MXU_PRIMS
 
 
 def test_throttle_flamegraph_localizes_better_than_cycles():

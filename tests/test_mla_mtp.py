@@ -38,7 +38,7 @@ def test_mla_absorbed_decode_matches_naive(setup):
 
 def test_mla_cache_is_compressed(setup):
     """The MLA cache stores the latent (kv_lora + rope), not full KV —
-    the property that makes migration/handoff cheap (DESIGN.md §2.4)."""
+    the property that makes migration/handoff cheap."""
     cfg, _ = setup
     cache = attn.mla_init_cache(cfg, 4, 64, jnp.float32)
     m = cfg.mla

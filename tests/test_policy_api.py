@@ -9,8 +9,7 @@ from repro.core.muqss import SchedConfig, Scheduler
 from repro.core.task import Task, TaskType
 from repro.sched import (AdaptivePolicy, CohortPolicy, SharedBaselinePolicy,
                          SpecializedPolicy, Topology, WorkKind)
-from repro.sched.engine import (Engine, PoolModel, ServeConfig,
-                                pool_model_from_dryrun)
+from repro.sched.engine import Engine, PoolModel, ServeConfig
 from repro.sched.workload import poisson_workload
 
 PM = PoolModel(prefill_ms_per_ktok=326.0, decode_fixed_ms=757.0,
@@ -232,33 +231,3 @@ def test_muqss_and_engine_share_one_policy_object():
     m = Engine(Topology.serving(8, 2), pol, PM).run(_workload(), 20_000)
     assert m.pool_busy["decode"]["heavy"] == 0.0
 
-
-# --------------------------------------- pool model dry-run derivation
-
-
-def _dryrun(status_pre="ok", status_dec="ok"):
-    return {
-        "a|prefill_32k|single": {
-            "status": status_pre,
-            "roofline": {"chips": 256, "step_s": 2.0}},
-        "a|decode_32k|single": {
-            "status": status_dec,
-            "roofline": {"chips": 256, "step_s": 0.004}},
-    }
-
-
-def test_pool_model_from_dryrun_ok():
-    pm = pool_model_from_dryrun(_dryrun(), "a")
-    assert pm.prefill_ms_per_ktok != PoolModel().prefill_ms_per_ktok
-    assert pm.prefill_ms_per_ktok == pytest.approx(
-        2.0 * 256 / (32 * 32768) * 1e6)
-
-
-def test_pool_model_from_dryrun_missing_arch_falls_back():
-    assert pool_model_from_dryrun(_dryrun(), "other") == PoolModel()
-
-
-def test_pool_model_from_dryrun_failed_entry_falls_back():
-    assert pool_model_from_dryrun(
-        _dryrun(status_dec="error"), "a") == PoolModel()
-    assert pool_model_from_dryrun({}, "a") == PoolModel()

@@ -1,6 +1,7 @@
-"""Device-pool specialization for serving (the TPU adaptation, DESIGN.md
-§2.2): interference and its mitigation, asymmetric-rule invariants —
-through the repro.sched Policy/Topology API."""
+"""Device-pool specialization for serving (the TPU adaptation: prefill
+and decode on separate device pools): interference and its mitigation,
+asymmetric-rule invariants — through the repro.sched Policy/Topology
+API."""
 import copy
 
 import pytest
