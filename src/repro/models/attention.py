@@ -49,13 +49,13 @@ def gqa_init(key, cfg: ArchConfig, dtype) -> dict:
     return p
 
 
-def _qkv(p, x, cfg: ArchConfig, positions):
+def _qkv(p, x, cfg: ArchConfig, positions, project=dense):
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     cdt = dt(cfg.compute_dtype)
-    q = dense(p["wq"], x, cdt).reshape(B, S, cfg.n_heads, hd)
-    k = dense(p["wk"], x, cdt).reshape(B, S, cfg.kv_heads, hd)
-    v = dense(p["wv"], x, cdt).reshape(B, S, cfg.kv_heads, hd)
+    q = project(p["wq"], x, cdt).reshape(B, S, cfg.n_heads, hd)
+    k = project(p["wk"], x, cdt).reshape(B, S, cfg.kv_heads, hd)
+    v = project(p["wv"], x, cdt).reshape(B, S, cfg.kv_heads, hd)
     if "q_scale" in p:
         q = rmsnorm(q, p["q_scale"])
         k = rmsnorm(k, p["k_scale"])
@@ -122,6 +122,15 @@ def row_groups(cache, stacked: bool = False) -> list:
     return out
 
 
+def decode_dense(p, x, cdt):
+    """``dense`` for a decode round's rows, its ``[B,1,n]`` output pinned
+    as the matmul lays it out. Attention reads a projection head-major;
+    left free, the compiler from two rows on has the matmul write that
+    layout, and to do so copies and transposes the weight, in every layer
+    of every call. Pinned, the reshape to heads moves the B·n outputs."""
+    return jax.lax.optimization_barrier(dense(p, x, cdt))
+
+
 def gqa_decode_token(p, x, cfg: ArchConfig, cache, lengths):
     """x: [B,1,d]; lengths[b] = number of tokens BEFORE this one. Attends
     over the cache's first ``lengths[b]`` positions and the token itself,
@@ -133,7 +142,7 @@ def gqa_decode_token(p, x, cfg: ArchConfig, cache, lengths):
     cdt = dt(cfg.compute_dtype)
     groups = row_groups(cache)
     positions = lengths[:, None]                            # [B,1]
-    q, k, v = _qkv(p, x, cfg, positions)
+    q, k, v = _qkv(p, x, cfg, positions, decode_dense)
     kv_dt = groups[0][1]["k"].dtype
     new = {"k": k[:, 0].astype(kv_dt), "v": v[:, 0].astype(kv_dt)}
     o = jnp.concatenate([
@@ -186,13 +195,13 @@ def mla_scale(cfg: ArchConfig) -> float:
     return scale
 
 
-def _mla_q(p, x, cfg, positions):
+def _mla_q(p, x, cfg, positions, project=dense):
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.n_heads
     cdt = dt(cfg.compute_dtype)
     qa = rmsnorm(dense(p["wq_a"], x, cdt), p["q_norm"]["scale"], cfg.norm_eps)
-    q = dense(p["wq_b"], qa, cdt).reshape(B, S, H, m.nope_head_dim + m.rope_head_dim)
+    q = project(p["wq_b"], qa, cdt).reshape(B, S, H, m.nope_head_dim + m.rope_head_dim)
     q_nope, q_rope = q[..., :m.nope_head_dim], q[..., m.nope_head_dim:]
     return q_nope, _mla_rope(q_rope, positions, cfg)
 
@@ -250,11 +259,19 @@ def mla_prefill(p, x, cfg: ArchConfig, cache, positions):
         return _mla_attend(p, x, cfg, positions, c_kv, k_rope), cache
 
 
-def _mla_wkv_b_split(p, cfg):
+# rows of a matrix that one of the chip's (8, 128) tiles holds
+TILE_ROWS = 8
+
+
+def _mla_wkv_b_tiles(p, cfg):
+    """``wkv_b`` ``[lora, H·(nope+v)]`` as ``[lora/8, H, 8, nope+v]``,
+    split into ``W_uk`` and ``W_uv``: the order in which the chip's tiles
+    hold its elements, so that read this way it is not relaid out."""
     m = cfg.mla
-    H = cfg.n_heads
-    w = p["wkv_b"]["w"].reshape(m.kv_lora_rank, H, m.nope_head_dim + m.v_head_dim)
-    return w[..., :m.nope_head_dim], w[..., m.nope_head_dim:]  # [lora,H,nope],[lora,H,v]
+    w = p["wkv_b"]["w"].reshape(m.kv_lora_rank // TILE_ROWS, TILE_ROWS,
+                                cfg.n_heads, m.nope_head_dim + m.v_head_dim)
+    w = w.transpose(0, 2, 1, 3)
+    return w[..., :m.nope_head_dim], w[..., m.nope_head_dim:]
 
 
 def mla_decode_token(p, x, cfg: ArchConfig, cache, lengths):
@@ -274,14 +291,28 @@ def _mla_decode_token(p, x, cfg: ArchConfig, cache, lengths):
     cdt = dt(cfg.compute_dtype)
     positions = lengths[:, None]
     groups = row_groups(cache)
-    q_nope, q_rope = _mla_q(p, x, cfg, positions)           # [B,1,H,*]
+    q_nope, q_rope = _mla_q(p, x, cfg, positions, decode_dense)  # [B,1,H,*]
     c_kv_new, k_rope_new = _mla_latent(p, x, cfg, positions)
     new = {"c_kv": c_kv_new[:, 0].astype(groups[0][1]["c_kv"].dtype),
            "k_rope": k_rope_new[:, 0].astype(groups[0][1]["k_rope"].dtype)}
-    w_uk, w_uv = _mla_wkv_b_split(p, cfg)
-    # absorb W_uk into q: q_lat [B,1,H,lora]
-    q_lat = jnp.einsum("bshn,lhn->bshl", q_nope.astype(cdt), w_uk.astype(cdt),
-                       preferred_element_type=jnp.float32)
+    w_uk, w_uv = _mla_wkv_b_tiles(p, cfg)                  # [t,H,8,nope|v]
+    f32 = jnp.float32
+
+    # The absorbed matmuls, one row at a time and as products and sums over
+    # wkv_b's tiles: an einsum has the compiler relay wkv_b out head-major
+    # for a row, and transpose it for a batch of rows, in every layer.
+    def absorb(qn):                                         # [1,H,nope]
+        s = jnp.sum(qn[:, None, :, None].astype(cdt).astype(f32)
+                    * w_uk.astype(cdt).astype(f32), -1)      # [1,t,H,8]
+        return s.transpose(0, 2, 1, 3).reshape(1, H, -1)    # [1,H,lora]
+
+    def unabsorb(ol):                                       # [1,H,lora]
+        ol = ol.astype(cdt).astype(f32).reshape(1, H, -1, TILE_ROWS)
+        return jnp.sum(ol.transpose(0, 2, 1, 3)[..., None]
+                       * w_uv.astype(cdt).astype(f32), (1, 3))  # [1,H,v]
+
+    q_lat = jnp.concatenate([absorb(q_nope[i:i + 1, 0])
+                             for i in range(B)])[:, None]   # [B,1,H,lora]
 
     def scores(r, ckv, krp):                                # -> [b,H,1,T]
         return (jnp.einsum("bshl,btl->bhst", q_lat[r].astype(cdt),
@@ -306,8 +337,8 @@ def _mla_decode_token(p, x, cfg: ArchConfig, cache, lengths):
 
     o_lat = jnp.concatenate([attend(r, c["c_kv"], c["k_rope"])
                              for r, c in groups])
-    o = jnp.einsum("bshl,lhv->bshv", o_lat.astype(cdt), w_uv.astype(cdt),
-                   preferred_element_type=jnp.float32)      # [B,1,H,v]
+    o = jnp.concatenate([unabsorb(o_lat[i:i + 1, 0])
+                         for i in range(B)])[:, None]       # [B,1,H,v]
     y = dense(p["wo"], o.reshape(B, 1, H * m.v_head_dim).astype(cdt), cdt)
     return y, new
 

@@ -1,7 +1,7 @@
 """Test helpers: run a snippet in a subprocess with N fake XLA devices
 (the main test process must keep seeing exactly one device), find the
-copies of a KV cache in compiled HLO text, and fix the service times an
-executor reports to the engine."""
+copies of a KV cache or of a weight in compiled HLO text, and fix the
+service times an executor reports to the engine."""
 import contextlib
 import os
 import re
@@ -45,6 +45,44 @@ def cache_copies(hlo: str, cache_shape) -> list:
                   or "dynamic-slice" in name)
         dims = set(re.findall(r"\[([\d,]*)\]", shape))
         if copies and dims & shapes:
+            out.append(line.strip()[:160])
+    return out
+
+
+# an array's shape, layout and tiling/memory space in HLO text
+_ARRAY = re.compile(r"\w+\[([\d,]*)\]\{([\d,]*)(?::([^}]*))?\}")
+
+
+def weight_copies(hlo: str, params, min_bytes: int = 8 * 2**20) -> list:
+    """The instructions of compiled HLO text that copy a leaf of
+    ``params`` over ``min_bytes``, or one layer's slice of a stacked leaf
+    ``[L, ...]``: copies, fusions named for a copy, and materialised
+    dynamic slices, of the leaf's own shape (a stacked leaf's also
+    ``[1, ...]`` and ``[...]``). A 2-D leaf matches by its exact shape
+    only, so that a bias as long as a matrix is wide does not. A copy into
+    the chip's fast memory (``S(1)``) that keeps the default layout is how
+    the compiler stages any read of a weight and is not counted; a copy
+    into another layout, or into HBM, is."""
+    shapes = set()
+    for w in jax.tree.leaves(params):
+        if w.size * w.dtype.itemsize > min_bytes:
+            shapes.add(tuple(w.shape))
+            if w.ndim > 2:
+                shapes |= {tuple(w.shape[1:]), (1, *w.shape[1:])}
+    out = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
+        if not m:
+            continue
+        name, shape, op = m.groups()
+        a = _ARRAY.search(shape)
+        if not a or not (op.startswith("copy") or name.startswith("copy")
+                         or "dynamic-slice" in name):
+            continue
+        dims = tuple(int(d) for d in a.group(1).split(",") if d)
+        default = a.group(2) == ",".join(map(str, range(len(dims) - 1, -1, -1)))
+        staged = default and "S(1)" in (a.group(3) or "")
+        if dims in shapes and not staged:
             out.append(line.strip()[:160])
     return out
 

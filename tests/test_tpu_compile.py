@@ -10,20 +10,27 @@ cache is off around these compiles: an entry compiled for a described
 device cannot be read back without one.
 """
 import dataclasses
+import functools
+import json
+import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from helpers import assert_updates_cache_in_place
-from repro.configs import get_arch
-from repro.dist.context import no_dist
-from repro.kernels.chacha20 import keystream
-from repro.kernels.decode_attention import flash_decode
-from repro.kernels.flash_attention import flash_attention
-from repro.launch.serve import jit_steps, placed, round_sizes, serve_steps
-from repro.models.api import build_model
+from helpers import REPO, assert_updates_cache_in_place, weight_copies
+
+sys.path.insert(0, str(REPO))
+
+from bench.harness import program_arch  # noqa: E402
+from repro.configs import get_arch  # noqa: E402
+from repro.dist.context import no_dist  # noqa: E402
+from repro.kernels.chacha20 import keystream  # noqa: E402
+from repro.kernels.decode_attention import flash_decode  # noqa: E402
+from repro.kernels.flash_attention import flash_attention  # noqa: E402
+from repro.launch.serve import jit_steps, placed, round_sizes, serve_steps  # noqa: E402
+from repro.models.api import build_model  # noqa: E402
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
 PROMPT, MAX_SEQ = 1024, 1152     # qwen1.5-0.5b serve shapes, batch 1
@@ -97,29 +104,45 @@ def test_qwen_decode_step_compiles(qwen, one_chip, size):
     assert_updates_cache_in_place(compiled, caches)
 
 
-# the DeepSeek share's decode temporaries: 4.9 MB for one sequence; from
-# two on, 78.5 MB at 2 to 81.2 MB at 8, as the compiler materialises a
-# MoE layer's `wq_b` slice (72 MB) to lay it out for more than one row. A
-# copy of a layer's held experts (704 MB), or of one expert (88 MB), breaks
-# either bound.
-TEMP_BOUND = {1: 64 * 2**20, **{n: 96 * 2**20 for n in SIZES[1:]}}
+# a decode round's temporaries: 6.5 MB for one sequence of the DeepSeek
+# share and 7.8 to 11.6 MB for two to eight; the starcoder2 stage's under
+# 2 MB. A copy of a layer's `wq_b` (75 MB), of its held experts (704 MB)
+# or of one expert (88 MB) breaks the bound.
+TEMP_BOUND = 16 * 2**20
+
+
+def _stage(cfg, prompt: int, max_seq: int, sharding):
+    """``cfg``'s parameters as shapes on ``sharding``, and its decode as
+    the executor compiles it for a round of a given size after a prompt of
+    ``prompt`` tokens: ``(compiled, caches)``, each size compiled once."""
+    model = build_model(cfg, no_dist())
+    params = jax.tree.map(lambda s: placed(s, sharding),
+                          model.abstract_params())
+    toks = placed(jax.ShapeDtypeStruct((1, prompt), jnp.int32), sharding)
+    prefill, decode = jit_steps(serve_steps(model, max_seq))
+    row = jax.tree.map(lambda s: placed(s, sharding),
+                       jax.eval_shape(prefill, params, toks))
+    return params, functools.cache(
+        lambda size: _round(decode, params, row, size))
 
 
 @pytest.fixture(scope="module")
 def deepseek_share(one_chip):
     """The DeepSeek-V3 share at published widths (the benchmark cell's 3
-    dense + 4 MoE layers and vocabulary slice): its jitted steps, its
-    parameters on one described chip, and prefill's output as shapes."""
+    dense + 4 MoE layers and vocabulary slice), after a 1k prompt."""
     cfg = dataclasses.replace(get_arch("deepseek-v3-671b-ep32"), n_layers=7,
                               vocab=16160)
-    model = build_model(cfg, no_dist())
-    params = jax.tree.map(lambda s: placed(s, one_chip),
-                          model.abstract_params())
-    toks = placed(jax.ShapeDtypeStruct((1, PROMPT), jnp.int32), one_chip)
-    prefill, decode = jit_steps(serve_steps(model, MAX_SEQ))
-    row = jax.tree.map(lambda s: placed(s, one_chip),
-                       jax.eval_shape(prefill, params, toks))
-    return decode, params, row
+    return _stage(cfg, PROMPT, MAX_SEQ, one_chip)
+
+
+@pytest.fixture(scope="module")
+def starcoder2_stage(one_chip):
+    """The starcoder2-15b pipeline stage of the benchmark cell (10 layers
+    at published widths, built as the harness builds it), after its 2k
+    prompts with room for their 128 tokens."""
+    conf = json.loads((REPO / "bench" / "configs" / "starcoder2-15b-pp4.json")
+                      .read_text())
+    return _stage(program_arch(conf), 2048, 2048 + 128, one_chip)
 
 
 @pytest.mark.parametrize("size", SIZES)
@@ -129,11 +152,25 @@ def test_deepseek_share_decode_reads_experts_in_place(deepseek_share, size):
     are copied. A layer loop that slices them out of the stack, or an
     expert axis in the tiled minor pair of a matrix, copies gigabytes
     every call."""
-    decode, params, row = deepseek_share
-    compiled, caches = _round(decode, params, row, size)
+    _, rounds = deepseek_share
+    compiled, caches = rounds(size)
     _fits(compiled)
     assert_updates_cache_in_place(compiled, caches)
-    assert compiled.memory_analysis().temp_size_in_bytes < TEMP_BOUND[size]
+    assert compiled.memory_analysis().temp_size_in_bytes < TEMP_BOUND
+
+
+@pytest.mark.parametrize("size", SIZES)
+@pytest.mark.parametrize("stage", ["starcoder2_stage", "deepseek_share"])
+def test_decode_round_copies_no_weight(request, stage, size):
+    """A decode round of ``size`` reads each weight where it lies: no
+    weight over 8 MiB, nor a layer's slice of one, is copied, relaid out
+    or materialised, at one row or at eight. From two rows on, a query
+    projection whose output the attention reads head-major has the
+    compiler transpose `wq` / `wq_b` in every layer; batched over rows,
+    the latent attention's absorbed matmuls do the same to `wkv_b`."""
+    params, rounds = request.getfixturevalue(stage)
+    compiled, _ = rounds(size)
+    assert weight_copies(compiled.as_text(), params) == []
 
 
 def _kernel_hlo(fn, *shapes, sharding):
